@@ -18,6 +18,8 @@ def main() -> None:
     ap.add_argument("--skip-collectives", action="store_true")
     args = ap.parse_args()
 
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     from . import kernels_bench, paper_tables, roofline
 
     suites = []

@@ -1,18 +1,15 @@
-"""compat-boundary: jax version-skew symbols only in compat.py (DESIGN.md §6, §11).
+"""compat-boundary: jax mesh/sharding APIs only in compat.py (DESIGN.md §6, §11).
 
-The repo supports both sides of the jax 0.4.x ↔ latest API skew through
-exactly one dispatch layer, ``src/repro/compat.py``.  Any call site that
-spells a skew API directly — modern-only (``jax.shard_map``,
-``jax.sharding.AxisType``, …) or 0.4.x-only (``jax.experimental
-.shard_map``, the ``check_rep``/``check_vma`` kwargs) — silently breaks
-one CI matrix leg.  This pass is the mechanical half of the §6 policy,
-migrated from the ad-hoc scan that used to live in
-``tests/test_compat.py`` (the test is now a thin wrapper over this
-pass).
+The repo spells the jax mesh and sharding entry points it uses
+(``shard_map``, ``make_mesh``, ``AxisType``, ``set_mesh``,
+``get_abstract_mesh``) once, in ``src/repro/compat.py``.  Any call site
+that spells one of them directly — or a retired spelling such as
+``jax.experimental.shard_map`` or the ``check_rep``/``check_vma``
+kwargs — is a finding.  ``tests/test_compat.py`` is a thin wrapper over
+this pass.
 
 ``compat.py`` itself is exempt (it *is* the boundary), as is
-``tests/test_compat.py`` (it pins the dispatch by asserting against
-both spellings).
+``tests/test_compat.py`` (it asserts against the jax spellings).
 """
 from __future__ import annotations
 
@@ -31,7 +28,7 @@ SKEW_PATTERN = re.compile(
     r"jax\.set_mesh|jax\.shard_map|jax\.make_mesh"
     r"|jax\.sharding\.AxisType|jax\.sharding\.get_abstract_mesh"
     r"|jax\.sharding\.use_mesh"
-    # 0.4.x-only spellings
+    # retired spellings
     r"|jax\.experimental\.shard_map"
     r"|check_vma|check_rep")
 
@@ -40,10 +37,10 @@ EXEMPT_BASENAMES = ("compat.py", "test_compat.py")
 
 
 class CompatBoundaryPass(LintPass):
-    """Line scan for skew jax APIs outside the compat layer."""
+    """Line scan for mesh/sharding jax APIs outside the compat layer."""
 
     name = "compat-boundary"
-    description = ("jax version-skew symbols (shard_map/make_mesh/"
+    description = ("jax mesh/sharding symbols (shard_map/make_mesh/"
                    "AxisType/check_rep/...) appear only in "
                    "src/repro/compat.py (DESIGN.md §6)")
     scope = ("src/*.py", "src/**/*.py", "tests/*.py", "tests/**/*.py",
@@ -60,7 +57,7 @@ class CompatBoundaryPass(LintPass):
             m = SKEW_PATTERN.search(line)
             if m:
                 yield self.finding(sf, ln, (
-                    f"skew jax API {m.group(0)!r} outside repro/compat.py "
+                    f"jax API {m.group(0)!r} outside repro/compat.py "
                     f"— route it through the compat layer (DESIGN.md §6)"))
 
 

@@ -1,133 +1,47 @@
-"""jax version-dispatch layer — the only place allowed to touch skew APIs.
+"""One spelling for the jax mesh and sharding APIs the repo uses (DESIGN.md §6).
 
-The container baseline is jax 0.4.37; the code targets the modern (≥ 0.6)
-sharding surface.  Every symbol whose name, location, or signature moved
-between those lines is re-exported from here with one spelling, so call
-sites never version-branch themselves (DESIGN.md §6).  The sweep that
-produced this list checked every ``jax.*`` attribute the repo references;
-the skew surface is exactly:
+The repo supports one jax: 0.9.0.  This module keeps the repo's own
+spelling of the few mesh/sharding entry points it calls, so a future
+jax move lands in one file:
 
-    ============================  ==========================  =================
-    modern (≥ 0.6)                0.4.x equivalent            exported here as
-    ============================  ==========================  =================
-    jax.shard_map                 jax.experimental.shard_map  shard_map
-      (check_vma=...)               (check_rep=...)             (check=...)
-    jax.make_mesh(axis_types=..)  jax.make_mesh (no kwarg)    make_mesh
-    jax.sharding.AxisType         (absent; GSPMD == Auto)     AxisType
-    jax.set_mesh(mesh) context    ``with mesh:`` legacy ctx   set_mesh
-    jax.sharding.                 thread_resources.env.       get_abstract_mesh
-      get_abstract_mesh()           physical_mesh
-    ============================  ==========================  =================
+    ===============================  ================================
+    exported here                    jax 0.9
+    ===============================  ================================
+    shard_map(..., check=)           jax.shard_map(..., check_vma=)
+    make_mesh (Auto axes default)    jax.make_mesh(..., axis_types=)
+    AxisType                         jax.sharding.AxisType
+    set_mesh                         jax.set_mesh
+    get_abstract_mesh                jax.sharding.get_abstract_mesh
+    ===============================  ================================
 
-Dispatch is by capability probe (``hasattr``), not version compare, so
-intermediate releases that grew one API but not another still resolve
-correctly.  Policy: a new jax API enters the codebase *only* by adding a
-row here first; tests/test_compat.py pins the dispatch behaviour on
-whichever side of the skew the installed jax falls.
+The ``compat-boundary`` lint rule (DESIGN.md §11) keeps these names from
+being spelled anywhere else.
 """
 from __future__ import annotations
 
-import contextlib
-import re
-from typing import Optional, Sequence, Tuple
+from typing import Optional, Sequence
 
 import jax
 
-
-def _parse_version(v: str) -> Tuple[int, int, int]:
-    """Leading-digit parse so pre-release tags ('0.7.0rc1') don't crash
-    package import; dispatch itself never consults the version."""
-    out = []
-    for part in v.split(".")[:3]:
-        m = re.match(r"\d+", part)
-        out.append(int(m.group()) if m else 0)
-    return tuple(out + [0] * (3 - len(out)))
+AxisType = jax.sharding.AxisType
+set_mesh = jax.set_mesh
+get_abstract_mesh = jax.sharding.get_abstract_mesh
 
 
-JAX_VERSION = _parse_version(jax.__version__)
-
-# -- capability probes (exported so tests can assert the dispatch taken) -----
-HAS_SHARD_MAP = hasattr(jax, "shard_map")
-HAS_AXIS_TYPES = hasattr(jax.sharding, "AxisType")
-HAS_SET_MESH = hasattr(jax, "set_mesh")
-HAS_ABSTRACT_MESH = hasattr(jax.sharding, "get_abstract_mesh")
-
-
-# -- shard_map ---------------------------------------------------------------
-# ≥ 0.6 promoted shard_map out of jax.experimental and renamed the
-# replication/varying-manual-axes check kwarg check_rep → check_vma.
-if HAS_SHARD_MAP:
-    def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map_04x
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-        return _shard_map_04x(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check)
-
-
-# -- axis types --------------------------------------------------------------
-if HAS_AXIS_TYPES:
-    AxisType = jax.sharding.AxisType
-else:
-    import enum
-
-    class AxisType(enum.Enum):
-        """Stand-in mirroring jax.sharding.AxisType's members.
-
-        On 0.4.x there are no typed mesh axes — GSPMD treats every axis
-        as what ≥ 0.6 calls Auto — so the values are accepted (and
-        dropped) by :func:`make_mesh` purely for signature parity.
-        """
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
+def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
+    """``jax.shard_map`` with the varying-manual-axes check off by
+    default (``check`` maps to ``check_vma``)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               devices=None, axis_types: Optional[Sequence] = None
               ) -> jax.sharding.Mesh:
-    """jax.make_mesh with Auto-typed axes on both sides of the skew.
-
-    ≥ 0.6 requires ``axis_types`` to opt the mesh out of explicit-sharding
-    mode; 0.4.x's make_mesh rejects the kwarg but behaves as all-Auto
-    anyway, so the intent is identical.
-    """
-    axis_shapes = tuple(axis_shapes)
+    """``jax.make_mesh`` with Auto-typed axes unless ``axis_types`` says
+    otherwise (explicit-sharding mode is opt-in)."""
     axis_names = tuple(axis_names)
-    if HAS_AXIS_TYPES:
-        if axis_types is None:
-            axis_types = (AxisType.Auto,) * len(axis_names)
-        return jax.make_mesh(axis_shapes, axis_names, devices=devices,
-                             axis_types=tuple(axis_types))
-    return jax.make_mesh(axis_shapes, axis_names, devices=devices)
-
-
-# -- ambient mesh ------------------------------------------------------------
-if HAS_SET_MESH:
-    set_mesh = jax.set_mesh
-else:
-    @contextlib.contextmanager
-    def set_mesh(mesh: jax.sharding.Mesh):
-        """0.4.x: the legacy ``with mesh:`` resource context is the ambient
-        mesh — with_sharding_constraint resolves bare PartitionSpecs
-        against it during pjit tracing, same as ≥ 0.6's set_mesh scope."""
-        with mesh:
-            yield mesh
-
-
-if HAS_ABSTRACT_MESH:
-    get_abstract_mesh = jax.sharding.get_abstract_mesh
-else:
-    def get_abstract_mesh():
-        """0.4.x: the thread-local physical mesh set by ``with mesh:``.
-
-        Returns a concrete Mesh rather than ≥ 0.6's AbstractMesh; both
-        carry the ``.empty`` / ``.shape`` surface callers rely on
-        (distributed/constraints.py), and outside any mesh context the
-        returned mesh is empty — matching ≥ 0.6's no-op contract.
-        """
-        from jax._src import mesh as _mesh_lib
-        return _mesh_lib.thread_resources.env.physical_mesh
+    if axis_types is None:
+        axis_types = (AxisType.Auto,) * len(axis_names)
+    return jax.make_mesh(tuple(axis_shapes), axis_names, devices=devices,
+                         axis_types=tuple(axis_types))

@@ -39,6 +39,7 @@ ordered list on a full enumeration.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import heapq
 import os
@@ -58,6 +59,14 @@ from .index import LightweightIndex
 # (REPRO_DEVICE_ENUM=force).
 DEVICE_AUTO_MAX_K = 8
 DEVICE_AUTO_MIN_EDGES = 2048
+
+
+# Monotone count of enumeration runs per driver: "host", "device_loop"
+# (the host-looped device driver), "resident" (the device-resident
+# deque), "resident_stall" (a resident run that finished on the
+# host-looped driver) and "fused" (one per query of a fused launch).
+# Callers read deltas to see which driver served a query.
+DRIVER_RUNS: collections.Counter = collections.Counter()
 
 
 def resolve_backend(idx: LightweightIndex, backend: Optional[str],
@@ -229,8 +238,12 @@ def enumerate_paths_idx(
             return _drive_resident(idx, chunk_size=chunk_size,
                                    count_only=count_only,
                                    deadline=deadline)
-        step = _device_step(idx) if resolved == "device" \
-            else _host_step(idx, constraint)
+        if resolved == "device":
+            DRIVER_RUNS["device_loop"] += 1
+            step = _device_step(idx)
+        else:
+            DRIVER_RUNS["host"] += 1
+            step = _host_step(idx, constraint)
         return _drive(idx, step, chunk_size=chunk_size,
                       count_only=count_only, first_n=first_n,
                       max_results=max_results, constraint=constraint,
@@ -481,10 +494,12 @@ def _drive_resident(idx: LightweightIndex, chunk_size: int,
     cfg = kops.deque_config(k + 1, chunk_size, max_deg)
     if max_deg == 0 or cfg.cap > DEVICE_SLOT_BUDGET \
             or chunk_size > cfg.arena_cap:
+        DRIVER_RUNS["device_loop"] += 1
         return _drive(idx, _device_step(idx), chunk_size=chunk_size,
                       count_only=count_only, first_n=None,
                       max_results=None, constraint=None, deadline=deadline)
 
+    DRIVER_RUNS["resident"] += 1
     dev = idx.device_arrays()
     stats = EnumStats()
     out_paths: List[np.ndarray] = []
@@ -529,6 +544,7 @@ def _drive_resident(idx: LightweightIndex, chunk_size: int,
             work: List[Tuple[np.ndarray, int, object]] = [
                 (rows[starts[j]:starts[j] + lens[j]], int(depths[j]), None)
                 for j in range(nc)]
+            DRIVER_RUNS["resident_stall"] += 1
             return _drive_from(idx, _device_step(idx), work, stats,
                                out_paths, out_lens, count,
                                chunk_size=chunk_size,

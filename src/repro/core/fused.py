@@ -32,8 +32,9 @@ from typing import List, Optional, Tuple
 import numpy as np
 
 from . import clock
-from .enumerate import (DEVICE_SLOT_BUDGET, EnumResult, EnumStats,
-                        _fanout_segments, _finalize, _trim_to_first_n)
+from .enumerate import (DEVICE_SLOT_BUDGET, DRIVER_RUNS, EnumResult,
+                        EnumStats, _fanout_segments, _finalize,
+                        _trim_to_first_n)
 from .graph import PAD
 from .index import LightweightIndex
 
@@ -92,6 +93,7 @@ def enumerate_fused_device(
     if any(ix.n != n for ix in indexes):
         raise ValueError("fused launches require one common graph")
     states = [_MemberState(ix) for ix in indexes]
+    DRIVER_RUNS["fused"] += len(states)
     k1max = max(ix.k for ix in indexes) + 1
     mfm = _next_pow2(max(int(st.dev.dst.shape[0]) for st in states))
 

@@ -1,10 +1,12 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 Responsibilities: shape/alignment padding (kernels demand block multiples),
-dtype plumbing, and the interpret switch — on the CPU validation container
-kernels execute via ``interpret=True`` (the Pallas interpreter runs the
-kernel body in Python); on TPU the same call sites compile to Mosaic.
-Set REPRO_PALLAS=off to route every op to its pure-jnp reference instead
+dtype plumbing, the XLA stages around each kernel (the frontier gathers
+and the emit/continue compaction), and the interpret switch — when JAX's
+backend is the CPU, kernels run with ``interpret=True`` (the Pallas
+interpreter); otherwise Mosaic compiles them (tests/test_tpu_compile.py
+compiles every frontier and semiring entry point for a TPU v5e).  Set
+REPRO_PALLAS=off to route every op to its pure-jnp reference instead
 (used to A/B the kernels inside the full system).
 """
 from __future__ import annotations
@@ -20,26 +22,31 @@ import numpy as np
 from . import ref
 from .decode_attention import decode_attention as _decode_pallas
 from .flash_attention import flash_attention as _flash_pallas
-from .frontier_expand import PAD, frontier_expand_masks as _frontier_pallas
+from .frontier_expand import PAD, frontier_masks as _frontier_pallas
 from .frontier_expand import frontier_fused_masks as _frontier_fused_pallas
 from .semiring_spmm import BLOCK, counting_spmm as _counting_pallas
 from .semiring_spmm import minplus_spmv as _minplus_pallas
 
-# Monotone count of frontier-expansion device dispatches (single-query,
-# fused and deque-round launches alike).  The fused-launch and deque
-# tests assert on deltas of this counter — it is the ground truth for
-# "one dispatch per expansion round" (DESIGN.md §9).
-_dispatch_count: int = 0
+# Monotone counts of frontier-expansion device dispatches (single-query,
+# fused and deque-round launches alike), keyed by the launch's pow2
+# fan-out bucket (the kernels' ``max_deg``).  The fused-launch and deque
+# tests assert on deltas of the total — it is the ground truth for "one
+# dispatch per expansion round" (DESIGN.md §9).
+_dispatches: dict[int, int] = {}
 
 
 def device_dispatch_count() -> int:
     """Total frontier-expansion kernel dispatches since process start."""
-    return _dispatch_count
+    return sum(_dispatches.values())
 
 
-def _count_dispatch() -> None:
-    global _dispatch_count
-    _dispatch_count += 1
+def device_dispatch_fanouts() -> dict[int, int]:
+    """Dispatches since process start per pow2 fan-out bucket (a copy)."""
+    return dict(_dispatches)
+
+
+def _count_dispatch(max_deg: int) -> None:
+    _dispatches[max_deg] = _dispatches.get(max_deg, 0) + 1
 
 
 def _interpret() -> bool:
@@ -115,6 +122,41 @@ def _next_pow2(x: int) -> int:
     return 1 << max(x - 1, 0).bit_length() if x > 1 else 1
 
 
+# Row width of the blocked mask prefix sum below.
+_SCAN_BLOCK = 64
+
+
+def _mask_rank(mask: jnp.ndarray) -> jnp.ndarray:
+    """Inclusive int32 prefix count of a 1-D bool mask (``jnp.cumsum``).
+
+    Blocked: the mask, padded with False to 64-wide rows, goes through
+    one {0,1} matmul against an upper-triangular ones matrix (exact:
+    bf16 holds 0/1, the row sums stay below 2^24 in f32), and a flat
+    prefix sum over the row totals adds the offsets.  A flat scan over
+    the 2^19 candidate slots of one launch takes the TPU compiler
+    several times longer than this form (PERF.md, Findings)."""
+    L = mask.shape[0]
+    padded = _pad_to(mask, 0, _SCAN_BLOCK, False)
+    tri = jnp.triu(jnp.ones((_SCAN_BLOCK, _SCAN_BLOCK), jnp.bfloat16))
+    inner = jnp.dot(padded.reshape(-1, _SCAN_BLOCK).astype(jnp.bfloat16),
+                    tri, preferred_element_type=jnp.float32
+                    ).astype(jnp.int32)
+    tot = inner[:, -1]
+    return (inner + (jnp.cumsum(tot) - tot)[:, None]).reshape(-1)[:L]
+
+
+def _compact(mask: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Positions of the True entries of a 1-D mask, ascending, padded
+    with 0 to the mask's length (``jnp.nonzero(size=, fill_value=0)``),
+    plus their count."""
+    L = mask.shape[0]
+    rank = _mask_rank(mask)
+    dest = jnp.where(mask, rank - 1, L)
+    idxs = jnp.zeros((L,), jnp.int32).at[dest].set(
+        jnp.arange(L, dtype=jnp.int32), mode="drop")
+    return idxs, rank[-1]
+
+
 def _children(paths: jnp.ndarray, vflat: jnp.ndarray, idxs: jnp.ndarray,
               depth: jnp.ndarray, max_deg: int) -> jnp.ndarray:
     """Materialize child rows for the compacted candidate indices: gather
@@ -134,7 +176,7 @@ def _frontier_expand_jit(
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray,
            jnp.ndarray]:
     """Masks (Pallas kernel or jnp ref) + compaction, one fused jit."""
-    C, k1 = paths.shape
+    k1 = paths.shape[1]
     depth = meta[0]
     b = jnp.clip(k1 - 2 - depth, 0, k1 - 1)   # budget k - depth - 1
     endb = jnp.take(end, b, axis=1)
@@ -143,19 +185,14 @@ def _frontier_expand_jit(
             paths, begin, endb, dst, depth, meta[1], max_deg, PAD)
     else:
         vnew, emit, cont, counters = _frontier_pallas(
-            paths, begin, endb, dst, meta, max_deg=max_deg,
+            paths, begin, endb, dst, depth, meta[1], max_deg=max_deg,
             interpret=interpret)
-    cap = C * max_deg
     vflat = vnew.reshape(-1)
-    flat_emit = emit.reshape(-1) != 0
-    eidx = jnp.nonzero(flat_emit, size=cap, fill_value=0)[0]
+    eidx, n_emit = _compact(emit.reshape(-1) != 0)
     emit_rows = _children(paths, vflat, eidx, depth, max_deg)
-    n_emit = jnp.sum(flat_emit.astype(jnp.int32))
     if want_cont:
-        flat_cont = cont.reshape(-1) != 0
-        cidx = jnp.nonzero(flat_cont, size=cap, fill_value=0)[0]
+        cidx, n_cont = _compact(cont.reshape(-1) != 0)
         cont_rows = _children(paths, vflat, cidx, depth, max_deg)
-        n_cont = jnp.sum(flat_cont.astype(jnp.int32))
     else:
         # last hop: survivors can never extend, so skip the (cap, k+1)
         # gather the caller would discard (counters still see them)
@@ -208,10 +245,11 @@ def frontier_expand(
     if C != rows:
         paths = np.pad(paths, ((0, C - rows), (0, 0)), constant_values=PAD)
     meta = jnp.asarray([depth, t], jnp.int32)
-    _count_dispatch()
+    max_deg = _next_pow2(max_deg)
+    _count_dispatch(max_deg)
     return _frontier_expand_jit(
         jnp.asarray(paths), jnp.asarray(fwd_begin), jnp.asarray(fwd_end),
-        jnp.asarray(fwd_dst), meta, max_deg=_next_pow2(max_deg),
+        jnp.asarray(fwd_dst), meta, max_deg=max_deg,
         interpret=_interpret(), use_ref=not _enabled(),
         want_cont=want_cont)
 
@@ -249,7 +287,6 @@ def _frontier_fused_jit(
     computes the full cont mask so dead-row and counter accounting
     matches the single-query kernel bit-for-bit.
     """
-    C, _k1 = paths.shape
     m = tvec.shape[0]
     if use_ref:
         vnew, emit, cont, counters = ref.frontier_fused_masks_ref(
@@ -258,17 +295,16 @@ def _frontier_fused_jit(
         vnew, emit, cont, counters = _frontier_fused_pallas(
             paths, rank, tvec, depthv, begin, endb, dst,
             max_deg=max_deg, interpret=interpret)
-    cap = C * max_deg
     vflat = vnew.reshape(-1)
     rankflat = jnp.repeat(rank, max_deg)
     depth_rows = jnp.take(depthv, rank)
     flat_emit = emit.reshape(-1) != 0
-    eidx = jnp.nonzero(flat_emit, size=cap, fill_value=0)[0]
+    eidx, _ = _compact(flat_emit)
     emit_rows = _children_fused(paths, vflat, eidx, depth_rows, max_deg)
     n_emit_m = jnp.zeros((m,), jnp.int32).at[rankflat].add(
         flat_emit.astype(jnp.int32))
     flat_cont = (cont.reshape(-1) != 0) & jnp.take(wantc, rankflat)
-    cidx = jnp.nonzero(flat_cont, size=cap, fill_value=0)[0]
+    cidx, _ = _compact(flat_cont)
     cont_rows = _children_fused(paths, vflat, cidx, depth_rows, max_deg)
     n_cont_m = jnp.zeros((m,), jnp.int32).at[rankflat].add(
         flat_cont.astype(jnp.int32))
@@ -308,12 +344,13 @@ def frontier_expand_fused(
     if C != rows:
         paths = np.pad(paths, ((0, C - rows), (0, 0)), constant_values=PAD)
         rank = np.pad(np.asarray(rank, np.int32), (0, C - rows))
-    _count_dispatch()
+    max_deg = _next_pow2(max_deg)
+    _count_dispatch(max_deg)
     return _frontier_fused_jit(
         jnp.asarray(paths), jnp.asarray(rank, dtype=jnp.int32),
         jnp.asarray(tvec, dtype=jnp.int32),
         jnp.asarray(depthv, dtype=jnp.int32), begin, endb, dst,
-        jnp.asarray(wantc, dtype=bool), max_deg=_next_pow2(max_deg),
+        jnp.asarray(wantc, dtype=bool), max_deg=max_deg,
         interpret=_interpret(), use_ref=not _enabled())
 
 
@@ -431,17 +468,14 @@ def _deque_round_jit(
             vnew, emit, cont, ctr1 = ref.frontier_masks_ref(
                 paths, begin, endb, dst, cdepth, t, cfg.max_deg, PAD)
         else:
-            meta = jnp.stack([cdepth, t]).astype(jnp.int32)
             vnew, emit, cont, ctr1 = _frontier_pallas(
-                paths, begin, endb, dst, meta, max_deg=cfg.max_deg,
+                paths, begin, endb, dst, cdepth, t, max_deg=cfg.max_deg,
                 interpret=interpret)
         s_ctr = s_ctr + ctr1
         vflat = vnew.reshape(-1)
 
-        flat_emit = emit.reshape(-1) != 0
-        eidx = jnp.nonzero(flat_emit, size=cap, fill_value=0)[0]
+        eidx, ne_new = _compact(emit.reshape(-1) != 0)
         echild = _children(paths, vflat, eidx, cdepth, cfg.max_deg)
-        ne_new = jnp.sum(flat_emit.astype(jnp.int32))
         s_eb = jax.lax.dynamic_update_slice(s_eb, echild,
                                             (s_ne, jnp.int32(0)))
         s_el = jax.lax.dynamic_update_slice(
@@ -452,8 +486,8 @@ def _deque_round_jit(
         # driver pushes pieces reversed) with intra-piece order intact
         wantc = cdepth + 1 < jnp.int32(k1 - 1)
         flat_cont = (cont.reshape(-1) != 0) & wantc
-        n_cont = jnp.sum(flat_cont.astype(jnp.int32))
-        crank = jnp.cumsum(flat_cont.astype(jnp.int32)) - 1
+        crank = _mask_rank(flat_cont) - 1
+        n_cont = crank[-1] + 1
         piece = crank // cs
         np_pieces = (n_cont + cs - 1) // cs
         dest = (s_top + n_cont - jnp.minimum((piece + 1) * cs, n_cont)
@@ -503,7 +537,7 @@ def frontier_deque_round(
     driver.  ``REPRO_PALLAS=off`` routes the mask stage to the ref
     oracle; counted as one device dispatch per round.
     """
-    _count_dispatch()
+    _count_dispatch(cfg.max_deg)
     return _deque_round_jit(arena, meta_depth, meta_len, top, n_chunks,
                             begin, end, dst, jnp.asarray(t, jnp.int32),
                             cfg=cfg, interpret=_interpret(),

@@ -43,12 +43,13 @@ def counting_spmm_ref(adj_mask: jnp.ndarray, counts: jnp.ndarray) -> jnp.ndarray
 
 def frontier_masks_ref(paths, begin, endb, dst, depth, t, max_deg: int,
                        pad: int = -1):
-    """Pure-jnp oracle for kernels/frontier_expand._frontier_kernel.
+    """Pure-jnp oracle for kernels/frontier_expand.frontier_masks (the
+    XLA gather stage plus ``_mask_kernel``).
 
     paths (C, k+1) int32 (PAD rows inert); begin/endb (n,) int32 offsets
     (endb pre-sliced to budget b = k - depth - 1); dst (mf,) int32;
     depth/t scalar int32.  Returns (vnew, emit, cont, counters) with the
-    same shapes, masking and Fig.-6 counter semantics as the kernel.
+    same shapes, masking and Fig.-6 counter semantics as frontier_masks.
     """
     C, k1 = paths.shape
     mf = dst.shape[0]
@@ -80,14 +81,15 @@ def frontier_masks_ref(paths, begin, endb, dst, depth, t, max_deg: int,
 
 def frontier_fused_masks_ref(paths, rank, tvec, depthv, begin, endb, dst,
                              max_deg: int, pad: int = -1):
-    """Pure-jnp oracle for kernels/frontier_expand._frontier_fused_kernel.
+    """Pure-jnp oracle for kernels/frontier_expand.frontier_fused_masks
+    (the XLA gather stage plus ``_mask_kernel``).
 
     paths (C, k1max) int32 rows packed member-rank-ascending (PAD rows
     inert); rank (C,) int32 member tags; tvec/depthv (m,) int32 per-member
     target/depth; begin/endb (m·n,) and dst (m·mfm,) int32 flattened
     per-member tables (endb pre-sliced to each member's budget column).
     Returns (vnew, emit, cont, counters) with counters (m, 4) per-member
-    Fig.-6 rows — same semantics as the fused kernel.
+    Fig.-6 rows — same semantics as frontier_fused_masks.
     """
     C, k1 = paths.shape
     m = tvec.shape[0]

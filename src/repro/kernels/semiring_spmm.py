@@ -35,8 +35,8 @@ BLOCK = 128
 
 def _minplus_kernel(adj_ref, dist_in_ref, dist_keep_ref, out_ref, *, inf):
     i = pl.program_id(1)  # reduction block index (rows u)
-    blk = adj_ref[...] + dist_in_ref[...].reshape(-1, 1)   # (BI, BJ)
-    part = jnp.min(blk, axis=0)                            # (BJ,)
+    blk = adj_ref[...] + dist_in_ref[...]                  # (BI, BJ)
+    part = jnp.min(blk, axis=0, keepdims=True)             # (1, BJ)
 
     @pl.when(i == 0)
     def _init():
@@ -50,25 +50,29 @@ def minplus_spmv(adj: jnp.ndarray, dist: jnp.ndarray, *, inf: float,
                  interpret: bool = False, block: int = BLOCK) -> jnp.ndarray:
     """One bounded-BFS relaxation over a dense (n, n) adjacency.
 
-    adj[u, v] = edge weight (1.0) or ``inf``; dist (n,) f32.
+    adj[u, v] = edge weight (1.0) or ``inf``; dist (n,) f32.  The
+    distance vector enters as a (n, 1) column (the reduction operand,
+    broadcast over a tile's columns) and as a (1, n) row (the kept
+    distances and the output): 2-D blocks whose layout Mosaic accepts.
     """
     n = adj.shape[0]
     assert adj.shape == (n, n) and dist.shape == (n,)
     assert n % block == 0, f"pad n={n} to a multiple of {block} (ops.py does)"
     nb = n // block
     grid = (nb, nb)  # (j: output block, i: reduction block)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_minplus_kernel, inf=inf),
         grid=grid,
         in_specs=[
             pl.BlockSpec((block, block), lambda j, i: (i, j)),
-            pl.BlockSpec((block,), lambda j, i: (i,)),
-            pl.BlockSpec((block,), lambda j, i: (j,)),
+            pl.BlockSpec((block, 1), lambda j, i: (i, 0)),
+            pl.BlockSpec((1, block), lambda j, i: (0, j)),
         ],
-        out_specs=pl.BlockSpec((block,), lambda j, i: (j,)),
-        out_shape=jax.ShapeDtypeStruct((n,), dist.dtype),
+        out_specs=pl.BlockSpec((1, block), lambda j, i: (0, j)),
+        out_shape=jax.ShapeDtypeStruct((1, n), dist.dtype),
         interpret=interpret,
-    )(adj, dist, dist)
+    )(adj, dist.reshape(n, 1), dist.reshape(1, n))
+    return out.reshape(n)
 
 
 # ---------------------------------------------------------------------------

@@ -1,15 +1,11 @@
-"""Pins repro.compat's version dispatch (DESIGN.md §6).
+"""Pins repro.compat's one spelling of the jax 0.9 mesh APIs (DESIGN.md §6).
 
-These run on whichever jax the environment ships; every assertion is
-phrased against the capability probes so both sides of the skew stay
-exercised (CI runs a pinned-0.4.x leg and a latest-jax leg).  The last
-test enforces the layer's policy mechanically: no skew API spelled
-outside src/repro/compat.py — it is a thin wrapper over the
+The last test enforces the layer's policy mechanically: no mesh/sharding
+API spelled outside src/repro/compat.py — it is a thin wrapper over the
 ``compat-boundary`` lint rule (DESIGN.md §11), which owns the symbol
 list and the exemptions.
 """
 import numpy as np
-import pytest
 
 import jax
 import jax.numpy as jnp
@@ -18,31 +14,19 @@ from jax.sharding import PartitionSpec as P
 from repro import compat
 
 
-def test_probes_match_installed_jax():
-    assert compat.HAS_SHARD_MAP == hasattr(jax, "shard_map")
-    assert compat.HAS_AXIS_TYPES == hasattr(jax.sharding, "AxisType")
-    assert compat.HAS_SET_MESH == hasattr(jax, "set_mesh")
-    assert compat.HAS_ABSTRACT_MESH == hasattr(jax.sharding,
-                                               "get_abstract_mesh")
-    assert compat.JAX_VERSION >= (0, 4)
-
-
 def test_axis_type_dispatch():
-    assert hasattr(compat.AxisType, "Auto")
-    if compat.HAS_AXIS_TYPES:
-        assert compat.AxisType is jax.sharding.AxisType
+    assert compat.AxisType is jax.sharding.AxisType
 
 
 def test_make_mesh_defaults_to_auto_axes():
     mesh = compat.make_mesh((1, 1), ("data", "model"))
     assert isinstance(mesh, jax.sharding.Mesh)
     assert dict(mesh.shape) == {"data": 1, "model": 1}
-    if compat.HAS_AXIS_TYPES:
-        assert all(t == compat.AxisType.Auto for t in mesh.axis_types)
+    assert all(t == compat.AxisType.Auto for t in mesh.axis_types)
 
 
 def test_ambient_mesh_roundtrip():
-    """set_mesh scopes the mesh get_abstract_mesh sees, on both sides."""
+    """set_mesh scopes the mesh get_abstract_mesh sees."""
     assert compat.get_abstract_mesh().empty
     mesh = compat.make_mesh((1,), ("data",))
     with compat.set_mesh(mesh):
@@ -63,7 +47,7 @@ def test_ambient_mesh_drives_constrain():
 
 
 def test_shard_map_unified_signature():
-    """One spelling covers check_vma (>= 0.6) and check_rep (0.4.x)."""
+    """``check`` is the one spelling of jax.shard_map's check_vma."""
     mesh = compat.make_mesh((1,), ("data",))
     f = compat.shard_map(lambda x: jax.lax.psum(x, "data"), mesh=mesh,
                          in_specs=(P("data"),), out_specs=P())
@@ -73,10 +57,10 @@ def test_shard_map_unified_signature():
 
 def test_no_skew_symbol_outside_compat():
     """Thin wrapper over the compat-boundary lint rule (DESIGN.md §11):
-    the rule owns the skew-symbol list and the compat.py exemption."""
+    the rule owns the symbol list and the compat.py exemption."""
     from repro.analysis import lint_repo
 
     report = lint_repo(rules=["compat-boundary"])
     assert not report.findings, (
-        "skew jax APIs must go through repro/compat.py:\n"
+        "mesh/sharding jax APIs must go through repro/compat.py:\n"
         + "\n".join(f.render() for f in report.findings))

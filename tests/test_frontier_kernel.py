@@ -313,6 +313,31 @@ def test_host_device_expand_bitwise_equal_seeded(seed):
     _assert_host_device_chunk_parity(_random_chunk_case(seed * 7919))
 
 
+@pytest.mark.parametrize("rows,fanout", [(64, 3000), (128, 700)])
+def test_wide_fanout_multiblock_parity(hub_graph, rows, fanout):
+    """Fan-outs that split the mask grid: past COL_BLOCK the slots span
+    several column blocks (dup/live counts summed across them), and wide
+    fan-outs take row blocks under BLOCK_ROWS.  Rows, order and counters
+    must still equal the host expansion; row prefixes hold leaves of the
+    hub's own list, so dups fall in every column block."""
+    from repro.kernels.frontier_expand import _blocks
+    hubs = 4
+    g = hub_graph(hubs, fanout, seed=rows)
+    idx = build_index(g, 0, 1, 3)
+    rng = np.random.default_rng(fanout)
+    paths = np.full((rows, 4), PAD, np.int32)
+    for r in range(rows):
+        h = 2 + r % hubs
+        own = idx.it(h, 1)
+        paths[r, :2] = (int(rng.choice(own)) if r % 3 else 0, h)
+    paths[-1, :2] = (0, g.n - 1)                 # dead row: z has no I_t
+    max_deg = 1 << (int(idx.fwd_end[2, 1] - idx.fwd_begin[2]) - 1).bit_length()
+    br, bc = _blocks(rows, max_deg)
+    assert rows // br > 1
+    assert (max_deg // bc > 1) == (fanout > 2048)
+    _assert_host_device_chunk_parity((idx, paths, 1))
+
+
 def test_fanout_segmentation_preserves_order_and_stats(monkeypatch):
     """A chunk cut into many fan-out segments (tiny DEVICE_SLOT_BUDGET)
     must produce the same paths, order and EnumStats as one launch — the

@@ -121,6 +121,23 @@ def test_fused_rejects_mixed_graphs():
         enumerate_fused_device(i1 + i2)
 
 
+def test_fused_wide_fanout_parity_with_host(hub_graph):
+    """Members whose hub fan-out (over 2,048) spans two column blocks of
+    the mask grid, with the hubs' back edges to s pruned as prefix
+    duplicates in the second block: results and EnumStats equal the
+    host engine's."""
+    g = hub_graph(2, 3000, seed=5)
+    idxs = _indexes(g, [(0, 1, 4), (0, 1, 3)])
+    assert len(idxs) == 2
+    assert int(idxs[0].fwd_end[2, 2] - idxs[0].fwd_begin[2]) > 2048
+    before = kops.device_dispatch_fanouts()
+    fused = enumerate_fused_device(idxs)
+    assert kops.device_dispatch_fanouts().get(4096, 0) > before.get(4096, 0)
+    for idx, fr in zip(idxs, fused):
+        _assert_equal(fr, enumerate_paths_idx(idx, backend="host"),
+                      f"s={idx.s} t={idx.t}")
+
+
 def test_fused_ref_oracle_leg(monkeypatch):
     """REPRO_PALLAS=off routes the fused dispatch through the pure-jnp
     oracle; results stay byte-identical."""
@@ -202,14 +219,22 @@ def test_batch_single_query_skips_fusion():
     assert out.fused_queries == 0
 
 
-def test_kernel_fused_matches_ref_oracle():
-    """Direct kernel-vs-oracle check on a packed multi-member chunk."""
+@pytest.mark.parametrize("m,n,mfm,C,max_deg", [
+    (4, 16, 32, 16, 8),
+    # fan-out past COL_BLOCK: two column blocks, 32-row blocks
+    (2, 64, 8192, 64, 4096),
+    # wide fan-out inside one column block: 64-row blocks
+    (3, 64, 2048, 128, 1024),
+])
+def test_kernel_fused_matches_ref_oracle(m, n, mfm, C, max_deg):
+    """Direct kernel-vs-oracle check on a packed multi-member chunk, at
+    grid shapes from one block to several row and column blocks."""
     import jax.numpy as jnp
     from repro.kernels import ref
     from repro.kernels.frontier_expand import frontier_fused_masks
 
     rng = np.random.default_rng(3)
-    m, n, mfm, C, k1, max_deg = 4, 16, 32, 16, 4, 8
+    k1 = 4
     paths = rng.integers(-1, n, (C, k1)).astype(np.int32)
     rank = np.sort(rng.integers(0, m, C)).astype(np.int32)
     tvec = rng.integers(0, n, m).astype(np.int32)
