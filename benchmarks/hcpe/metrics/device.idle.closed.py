@@ -1,0 +1,10 @@
+"""The device's idle share of the traced window, in percent: 1 minus the
+union of the intervals in which an op ran, over the window."""
+
+
+def read(rec):
+    """Idle percent of the window."""
+    tr = rec["trace"]
+    if tr is None or tr["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])
