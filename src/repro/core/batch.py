@@ -38,6 +38,7 @@ from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
+from .. import trace
 from . import planner as planner_mod
 from . import sharing as sharing_mod
 from .enumerate import EnumResult, EnumStats, enumerate_paths_idx
@@ -529,9 +530,10 @@ class BatchPathEnum:
                           if k in precomputed})
         unmasked = [k for k in missing if k[4] == 0 and k not in dists]
         if unmasked:
-            t0 = time.perf_counter()
-            dists.update(self._stacked_dists(graph, unmasked, group_builds))
-            timing.distance_seconds += time.perf_counter() - t0
+            with trace.span("pathenum.index.bfs") as sp:
+                dists.update(self._stacked_dists(graph, unmasked,
+                                                 group_builds))
+            timing.distance_seconds += sp.seconds
 
         build_graph = graph
         eff_mask = edge_mask
@@ -539,49 +541,49 @@ class BatchPathEnum:
             # one filtered graph serves every masked miss; building on it
             # (mask dropped) is byte-identical to the per-key masked
             # build, which constructs exactly this graph internally
-            t0 = time.perf_counter()
-            keep = np.asarray(edge_mask, dtype=bool)
-            edges = np.stack([graph.esrc[keep], graph.edst[keep]], axis=1)
-            build_graph = from_edges(graph.n, edges, dedup=False)
-            eff_mask = None
-            masked_missing = [kk for kk in missing if kk not in dists]
-            if masked_missing:
-                dists.update(self._stacked_dists(build_graph, masked_missing,
-                                                 group_builds))
-            timing.distance_seconds += time.perf_counter() - t0
+            with trace.span("pathenum.index.bfs") as sp:
+                keep = np.asarray(edge_mask, dtype=bool)
+                edges = np.stack([graph.esrc[keep], graph.edst[keep]],
+                                 axis=1)
+                build_graph = from_edges(graph.n, edges, dedup=False)
+                eff_mask = None
+                masked_missing = [kk for kk in missing if kk not in dists]
+                if masked_missing:
+                    dists.update(self._stacked_dists(
+                        build_graph, masked_missing, group_builds))
+            timing.distance_seconds += sp.seconds
 
-        built: Dict[QueryKey, LightweightIndex] = {}
-        if group_builds:
-            groupable = [kk for kk in missing if kk in dists]
-            for grp in sharing_mod.detect_groups(groupable):
-                t0 = time.perf_counter()
-                idxs = sharing_mod.build_member_indexes(
-                    build_graph,
-                    [(kk[1], kk[2], kk[3]) for kk in grp.keys],
-                    [dists[kk] for kk in grp.keys])
-                timing.index_seconds += time.perf_counter() - t0
-                built.update(zip(grp.keys, idxs))
+        with trace.span("pathenum.index.build") as sp:
+            built: Dict[QueryKey, LightweightIndex] = {}
+            if group_builds:
+                groupable = [kk for kk in missing if kk in dists]
+                for grp in sharing_mod.detect_groups(groupable):
+                    idxs = sharing_mod.build_member_indexes(
+                        build_graph,
+                        [(kk[1], kk[2], kk[3]) for kk in grp.keys],
+                        [dists[kk] for kk in grp.keys])
+                    built.update(zip(grp.keys, idxs))
 
-        for key in missing:
-            _, s, t, k, _mh, _gv = key
-            t0 = time.perf_counter()
-            if key in built:
-                idx = built[key]
-            elif key in dists:
-                # the mask still threads through: build_index must filter
-                # the edge set even when the distances are precomputed,
-                # or masked-out edges leak into the index (the distances
-                # themselves are the caller's contract — computed on the
-                # same filtered graph)
-                d_s, d_t = dists[key]
-                idx = build_index(build_graph, s, t, k,
-                                  dist_fn=lambda *_a, _d=(d_s, d_t): _d,
-                                  edge_mask=eff_mask)
-            else:  # masked query — BFS must run on the filtered graph
-                idx = build_index(build_graph, s, t, k, edge_mask=eff_mask)
-            timing.index_seconds += time.perf_counter() - t0
-            self.cache.put(key, idx)
-            resolved[key] = (idx, False)
+            for key in missing:
+                _, s, t, k, _mh, _gv = key
+                if key in built:
+                    idx = built[key]
+                elif key in dists:
+                    # the mask still threads through: build_index must
+                    # filter the edge set even when the distances are
+                    # precomputed, or masked-out edges leak into the
+                    # index (the distances themselves are the caller's
+                    # contract — computed on the same filtered graph)
+                    d_s, d_t = dists[key]
+                    idx = build_index(build_graph, s, t, k,
+                                      dist_fn=lambda *_a, _d=(d_s, d_t): _d,
+                                      edge_mask=eff_mask)
+                else:  # masked query — BFS must run on the filtered graph
+                    idx = build_index(build_graph, s, t, k,
+                                      edge_mask=eff_mask)
+                self.cache.put(key, idx)
+                resolved[key] = (idx, False)
+        timing.index_seconds += sp.seconds
         return resolved
 
     def _stacked_dists(self, graph: Graph, keys: List[QueryKey],
@@ -637,7 +639,8 @@ class BatchPathEnum:
                                              backend=self.engine.backend)
             cut = dp_plan.cut if dp_plan.cut else max(1, k // 2)
             return Plan(method="join", cut=cut, preliminary=-1.0,
-                        used_full_estimator=True)
+                        used_full_estimator=True,
+                        optimize_seconds=dp_plan.optimize_seconds)
         raise ValueError(f"unknown mode {mode!r}")
 
     # -- enumeration --------------------------------------------------------
@@ -712,6 +715,25 @@ class BatchPathEnum:
         the index build; only the BFS is skipped).
         """
         t_batch = time.perf_counter()
+        with trace.span("pathenum.batch"):
+            out = self._run(graph, queries, count_only, first_n, mode,
+                            edge_mask, deadline, graph_id, order, weights,
+                            sharing, _precomputed_distances)
+        out.timing.started_at = t_batch
+        out.timing.ended_at = time.perf_counter()
+        out.timing.total_seconds = out.timing.ended_at - t_batch
+        return out
+
+    def _run(self, graph: Graph, queries: Sequence[Tuple[int, int, int]],
+             count_only: bool, first_n: Optional[int], mode: str,
+             edge_mask: Optional[np.ndarray], deadline: Optional[float],
+             graph_id: str, order: Optional[str],
+             weights: Optional[np.ndarray], sharing: Optional[str],
+             precomputed: Optional[Dict[QueryKey, Tuple[np.ndarray,
+                                                        np.ndarray]]],
+             ) -> BatchOutput:
+        """`run`'s phases: indexes, plans, then the shared, fused and
+        solo enumerations, each timed by its span (DESIGN.md §12)."""
         timing = BatchTiming()
         stats_before = self.cache.stats.snapshot()
         for (s, t, k) in queries:
@@ -723,12 +745,22 @@ class BatchPathEnum:
         gv = int(graph.version)
         keys = [(graph_id, int(s), int(t), int(k), mh, gv)
                 for (s, t, k) in queries]
+        distinct = list(dict.fromkeys(keys))
         eff_sharing: str = sharing_mod.resolve_sharing(
             self.sharing if sharing is None else sharing)
 
-        resolved = self._indexes_for(graph, keys, edge_mask,
-                                     _precomputed_distances, timing,
+        resolved = self._indexes_for(graph, keys, edge_mask, precomputed,
+                                     timing,
                                      group_builds=eff_sharing == "auto")
+
+        plans: Dict[QueryKey, Plan] = {}
+
+        def plan_all(todo: List[QueryKey]) -> None:
+            for key in todo:
+                if key not in plans:
+                    plans[key] = self._plan_for(resolved[key][0], key[3],
+                                                mode)
+                    timing.optimize_seconds += plans[key].optimize_seconds
 
         # sharing phase (DESIGN.md §13): plan the distinct keys up front,
         # then serve whole overlap groups off one shared prefix walk.
@@ -737,26 +769,17 @@ class BatchPathEnum:
         # sharing only.
         shared_results: Dict[QueryKey, EnumResult] = {}
         shared_latency: Dict[QueryKey, float] = {}
-        plans_pre: Dict[QueryKey, Plan] = {}
-        plan_wall: Dict[QueryKey, float] = {}
         n_groups = 0
         if eff_sharing == "auto" and order is None:
-            for key in keys:
-                if key in plans_pre:
-                    continue
-                t0 = time.perf_counter()
-                plan = self._plan_for(resolved[key][0], key[3], mode)
-                plan_wall[key] = time.perf_counter() - t0
-                timing.optimize_seconds += plan.optimize_seconds
-                plans_pre[key] = plan
-            if len(plans_pre) > 1:
-                t1 = time.perf_counter()
-                shared_results, shared_latency, n_groups = \
-                    sharing_mod.run_shared_groups(
-                        self, resolved, plans_pre, count_only=count_only,
-                        first_n=first_n, deadline=deadline,
-                        graph_id=graph_id)
-                timing.enumerate_seconds += time.perf_counter() - t1
+            plan_all(distinct)
+            if len(plans) > 1:
+                with trace.span("pathenum.enum.shared") as sp:
+                    shared_results, shared_latency, n_groups = \
+                        sharing_mod.run_shared_groups(
+                            self, resolved, plans, count_only=count_only,
+                            first_n=first_n, deadline=deadline,
+                            graph_id=graph_id)
+                timing.enumerate_seconds += sp.seconds
 
         # fused device phase (DESIGN.md §9): the remaining dfs-plan
         # queries that resolve to the device backend enumerate together
@@ -772,79 +795,73 @@ class BatchPathEnum:
             from ..kernels import ops as kops   # lazy: pallas path only
             from . import fused as fused_mod
             from .enumerate import resolve_backend
-            for key in keys:
-                if key in plans_pre:
-                    continue
-                t0 = time.perf_counter()
-                plan = self._plan_for(resolved[key][0], key[3], mode)
-                plan_wall[key] = time.perf_counter() - t0
-                timing.optimize_seconds += plan.optimize_seconds
-                plans_pre[key] = plan
-            elig = [kk for kk in dict.fromkeys(keys)
+            plan_all(distinct)
+            elig = [kk for kk in distinct
                     if kk not in shared_results
-                    and plans_pre[kk].method == "dfs"
+                    and plans[kk].method == "dfs"
                     and resolve_backend(resolved[kk][0],
                                         self.engine.backend) == "device"]
             if len(elig) >= 2:
-                t1 = time.perf_counter()
                 before = kops.device_dispatch_count()
-                res_list = fused_mod.enumerate_fused_device(
-                    [resolved[kk][0] for kk in elig],
-                    chunk_size=self.engine.chunk_size,
-                    count_only=count_only, first_n=first_n,
-                    deadline=deadline)
+                with trace.span("pathenum.enum.fused") as sp:
+                    res_list = fused_mod.enumerate_fused_device(
+                        [resolved[kk][0] for kk in elig],
+                        chunk_size=self.engine.chunk_size,
+                        count_only=count_only, first_n=first_n,
+                        deadline=deadline)
                 fused_dispatches = kops.device_dispatch_count() - before
-                wall = time.perf_counter() - t1
-                timing.enumerate_seconds += wall
+                timing.enumerate_seconds += sp.seconds
                 fused_results = dict(zip(elig, res_list))
-                share = wall / len(elig)
+                share = sp.seconds / len(elig)
                 fused_latency = {kk: share for kk in elig}
 
-        items: List[Optional[BatchItem]] = [None] * len(keys)
+        # solo phase: every distinct query no shared walk or fused launch
+        # served runs its own pipeline, in first-occurrence order
+        solo = [kk for kk in distinct
+                if kk not in shared_results and kk not in fused_results]
+        plan_all(solo)
+        solo_results: Dict[QueryKey, EnumResult] = {}
+        solo_latency: Dict[QueryKey, float] = {}
+        if solo:
+            with trace.span("pathenum.enum.solo") as sp:
+                for key in solo:
+                    t1 = time.perf_counter()
+                    solo_results[key] = self._enumerate(
+                        resolved[key][0], plans[key], count_only, first_n,
+                        deadline, order=order, weights=weights)
+                    solo_latency[key] = time.perf_counter() - t1
+            timing.enumerate_seconds += sp.seconds
+
+        # a query's attributable work: its plan plus its enumeration (a
+        # fused launch's wall split evenly over its members); a duplicate
+        # reuses its twin's result and adds none
+        items: List[BatchItem] = []
         memo: Dict[QueryKey, BatchItem] = {}
-        for pos, key in enumerate(keys):
-            t0 = time.perf_counter()
+        for key in keys:
             prior = memo.get(key)
             if prior is not None:
-                items[pos] = dataclasses.replace(
+                items.append(dataclasses.replace(
                     prior, deduplicated=True, index_cached=True,
-                    latency_seconds=time.perf_counter() - t0)
+                    latency_seconds=0.0))
                 continue
             idx, was_cached = resolved[key]
-            plan_opt = plans_pre.get(key)
-            if plan_opt is None:
-                plan = self._plan_for(idx, key[3], mode)
-                timing.optimize_seconds += plan.optimize_seconds
+            plan = plans[key]
+            if key in shared_results:
+                res, wall = shared_results[key], shared_latency[key]
+            elif key in fused_results:
+                res, wall = fused_results[key], fused_latency[key]
             else:
-                plan = plan_opt
-            res_opt = shared_results.get(key)
-            fused_opt = fused_results.get(key)
-            if res_opt is not None:
-                res = res_opt
-                extra = shared_latency[key] + plan_wall.get(key, 0.0)
-            elif fused_opt is not None:
-                res = fused_opt
-                extra = fused_latency[key] + plan_wall.get(key, 0.0)
-            else:
-                extra = plan_wall.get(key, 0.0)
-                t1 = time.perf_counter()
-                res = self._enumerate(idx, plan, count_only, first_n,
-                                      deadline, order=order, weights=weights)
-                timing.enumerate_seconds += time.perf_counter() - t1
+                res, wall = solo_results[key], solo_latency[key]
             item = BatchItem(s=key[1], t=key[2], k=key[3], result=res,
                              plan=plan, index_cached=was_cached,
                              deduplicated=False,
-                             latency_seconds=(time.perf_counter() - t0
-                                              + extra),
-                             shared=res_opt is not None,
-                             fused=fused_opt is not None)
+                             latency_seconds=wall + plan.optimize_seconds,
+                             shared=key in shared_results,
+                             fused=key in fused_results)
             memo[key] = item
-            items[pos] = item
+            items.append(item)
 
-        timing.started_at = t_batch
-        timing.ended_at = time.perf_counter()
-        timing.total_seconds = timing.ended_at - t_batch
-        return BatchOutput(items=list(items), timing=timing,  # type: ignore[arg-type]
+        return BatchOutput(items=items, timing=timing,
                            cache_stats=self.cache.stats.delta(stats_before),
                            distinct_queries=len(memo), graph_id=graph_id,
                            sharing_groups=n_groups,

@@ -39,7 +39,7 @@ ordered list on a full enumeration.
 """
 from __future__ import annotations
 
-import collections
+import collections.abc
 import dataclasses
 import heapq
 import os
@@ -47,6 +47,7 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
+from .. import trace
 from . import clock, rank
 from .graph import PAD
 from .index import LightweightIndex
@@ -61,12 +62,30 @@ DEVICE_AUTO_MAX_K = 8
 DEVICE_AUTO_MIN_EDGES = 2048
 
 
-# Monotone count of enumeration runs per driver: "host", "device_loop"
-# (the host-looped device driver), "resident" (the device-resident
-# deque), "resident_stall" (a resident run that finished on the
-# host-looped driver) and "fused" (one per query of a fused launch).
-# Callers read deltas to see which driver served a query.
-DRIVER_RUNS: collections.Counter = collections.Counter()
+# Enumeration runs per driver, counted in the ``repro.trace`` tally as
+# ``pathenum.driver.<name>``: "host", "device_loop" (the host-looped
+# device driver), "resident" (the device-resident deque),
+# "resident_stall" (a resident run that finished on the host-looped
+# driver) and "fused" (one per query of a fused launch).
+DRIVER = "pathenum.driver."
+
+
+class _DriverRuns(collections.abc.Mapping):  # type: ignore[type-arg]
+    """``DRIVER_RUNS``: a read-only view of the per-driver run counters;
+    a driver that never ran reads 0.  Callers read deltas to see which
+    driver served a query."""
+
+    def __getitem__(self, name: str) -> int:
+        return trace.counter(DRIVER + name)
+
+    def __iter__(self):  # type: ignore[no-untyped-def]
+        return iter([k[len(DRIVER):] for k in trace.counters(DRIVER)])
+
+    def __len__(self) -> int:
+        return len(trace.counters(DRIVER))
+
+
+DRIVER_RUNS = _DriverRuns()
 
 
 def resolve_backend(idx: LightweightIndex, backend: Optional[str],
@@ -239,10 +258,10 @@ def enumerate_paths_idx(
                                    count_only=count_only,
                                    deadline=deadline)
         if resolved == "device":
-            DRIVER_RUNS["device_loop"] += 1
+            trace.count(DRIVER + "device_loop")
             step = _device_step(idx)
         else:
-            DRIVER_RUNS["host"] += 1
+            trace.count(DRIVER + "host")
             step = _host_step(idx, constraint)
         return _drive(idx, step, chunk_size=chunk_size,
                       count_only=count_only, first_n=first_n,
@@ -418,6 +437,14 @@ def _fanout_segments(cnt: np.ndarray, budget: int) -> List[Tuple[int, int]]:
     return segments
 
 
+def _count_d2h(nbytes: int, live: Optional[int] = None) -> None:
+    """Count ``nbytes`` copied from the device to the host, of which
+    ``live`` (all, by default) are rows and numbers the driver uses."""
+    trace.count("pathenum.xfer.d2h_bytes", nbytes)
+    trace.count("pathenum.xfer.d2h_live_bytes",
+                nbytes if live is None else live)
+
+
 def _device_step(idx: LightweightIndex):
     """The Pallas expansion step (DESIGN.md §9): one kernel launch per
     fan-out segment of the chunk, Fig.-6 counters accumulated from the
@@ -439,21 +466,28 @@ def _device_step(idx: LightweightIndex):
         emit_parts: List[np.ndarray] = []
         cont_parts: List[np.ndarray] = []
         for lo, hi in _fanout_segments(cnt, DEVICE_SLOT_BUDGET):
-            emit_rows, cont_rows, n_emit, n_cont, counters = \
-                kops.frontier_expand(paths[lo:hi], dev.begin, dev.end,
-                                     dev.dst, depth=depth, t=t,
-                                     max_deg=max(int(cnt[lo:hi].max()), 1),
-                                     want_cont=want_cont)
-            edges, partials, invalid, _ = (int(x) for x in
-                                           np.asarray(counters))
+            with trace.span("pathenum.enum.dispatch"):
+                emit_rows, cont_rows, n_emit, n_cont, counters = \
+                    kops.frontier_expand(
+                        paths[lo:hi], dev.begin, dev.end, dev.dst,
+                        depth=depth, t=t,
+                        max_deg=max(int(cnt[lo:hi].max()), 1),
+                        want_cont=want_cont)
+            with trace.span("pathenum.enum.sync"):
+                ctr = np.asarray(counters)
+                ne, nc = int(n_emit), int(n_cont)
+                if ne:
+                    emit_parts.append(np.asarray(emit_rows[:ne]))
+                if want_cont and nc:
+                    cont_parts.append(np.asarray(cont_rows[:nc]))
+            # every byte copied back is live: counts, counters and the
+            # emitted and continued rows, sliced on the device
+            _count_d2h(ctr.nbytes + 8 + 4 * (k + 1) * (
+                ne + (nc if want_cont else 0)))
+            edges, partials, invalid, _ = (int(x) for x in ctr)
             stats.edges_accessed += edges
             stats.partials_generated += partials
             stats.invalid_partials += invalid
-            ne, nc = int(n_emit), int(n_cont)
-            if ne:
-                emit_parts.append(np.asarray(emit_rows[:ne]))
-            if want_cont and nc:
-                cont_parts.append(np.asarray(cont_rows[:nc]))
         # one array per chunk, like the host step: _trim_to_first_n
         # trims only the driver's last appended block
         emit_out = (np.concatenate(emit_parts, axis=0)
@@ -494,12 +528,12 @@ def _drive_resident(idx: LightweightIndex, chunk_size: int,
     cfg = kops.deque_config(k + 1, chunk_size, max_deg)
     if max_deg == 0 or cfg.cap > DEVICE_SLOT_BUDGET \
             or chunk_size > cfg.arena_cap:
-        DRIVER_RUNS["device_loop"] += 1
+        trace.count(DRIVER + "device_loop")
         return _drive(idx, _device_step(idx), chunk_size=chunk_size,
                       count_only=count_only, first_n=None,
                       max_results=None, constraint=None, deadline=deadline)
 
-    DRIVER_RUNS["resident"] += 1
+    trace.count(DRIVER + "resident")
     dev = idx.device_arrays()
     stats = EnumStats()
     out_paths: List[np.ndarray] = []
@@ -514,37 +548,48 @@ def _drive_resident(idx: LightweightIndex, chunk_size: int,
         if deadline is not None and clock.expired(deadline):
             return _finalize(idx, out_paths, out_lens, count, stats,
                              exhausted=False)
-        arena, m_depth, m_len, top, n_chunks, emitbuf, emitlen, n_emit, \
-            counters, pops = kops.frontier_deque_round(
-                arena, m_depth, m_len, top, n_chunks, dev.begin, dev.end,
-                dev.dst, t, cfg=cfg)
-        stats.chunks += int(pops)
-        edges, partials, invalid, _ = (int(x) for x in np.asarray(counters))
+        with trace.span("pathenum.enum.dispatch"):
+            arena, m_depth, m_len, top, n_chunks, emitbuf, emitlen, \
+                n_emit, counters, pops = kops.frontier_deque_round(
+                    arena, m_depth, m_len, top, n_chunks, dev.begin,
+                    dev.end, dev.dst, t, cfg=cfg)
+        with trace.span("pathenum.enum.sync"):
+            n_pops = int(pops)
+            ctr = np.asarray(counters)
+            ne = int(n_emit)
+            if ne and not count_only:
+                out_paths.append(np.asarray(emitbuf[:ne]))
+                out_lens.append(np.asarray(emitlen[:ne]))
+            nc = int(n_chunks)
+        # pops, counters, n_emit, n_chunks and the emitted rows with
+        # their lengths, all live
+        _count_d2h(ctr.nbytes + 12 + (0 if count_only
+                                      else 4 * (k + 2) * ne))
+        trace.count("pathenum.enum.slots", n_pops * cfg.cap)
+        stats.chunks += n_pops
+        edges, partials, invalid, _ = (int(x) for x in ctr)
         stats.edges_accessed += edges
         stats.partials_generated += partials
         stats.invalid_partials += invalid
-        ne = int(n_emit)
         if ne:
             count += ne
             stats.results += ne
-            if not count_only:
-                out_paths.append(np.asarray(emitbuf[:ne]))
-                out_lens.append(np.asarray(emitlen[:ne]))
-        nc = int(n_chunks)
         if nc == 0:
             break
-        if int(pops) == 0:
+        if n_pops == 0:
             # capacity stall: rebuild the host work list (meta slots
             # bottom→top; list.pop() then takes the top chunk first,
             # preserving the LIFO order) and finish on the host loop
-            rows = np.asarray(arena[:int(top)])
-            lens = np.asarray(m_len[:nc]).astype(np.int64)
-            depths = np.asarray(m_depth[:nc])
+            with trace.span("pathenum.enum.sync"):
+                rows = np.asarray(arena[:int(top)])
+                lens = np.asarray(m_len[:nc]).astype(np.int64)
+                depths = np.asarray(m_depth[:nc])
+            _count_d2h(4 + rows.nbytes + 8 * nc)
             starts = np.concatenate([[0], np.cumsum(lens)[:-1]])
             work: List[Tuple[np.ndarray, int, object]] = [
                 (rows[starts[j]:starts[j] + lens[j]], int(depths[j]), None)
                 for j in range(nc)]
-            DRIVER_RUNS["resident_stall"] += 1
+            trace.count(DRIVER + "resident_stall")
             return _drive_from(idx, _device_step(idx), work, stats,
                                out_paths, out_lens, count,
                                chunk_size=chunk_size,

@@ -31,9 +31,10 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
+from .. import trace
 from . import clock
-from .enumerate import (DEVICE_SLOT_BUDGET, DRIVER_RUNS, EnumResult,
-                        EnumStats, _fanout_segments, _finalize,
+from .enumerate import (DEVICE_SLOT_BUDGET, DRIVER, EnumResult, EnumStats,
+                        _count_d2h, _fanout_segments, _finalize,
                         _trim_to_first_n)
 from .graph import PAD
 from .index import LightweightIndex
@@ -93,134 +94,153 @@ def enumerate_fused_device(
     if any(ix.n != n for ix in indexes):
         raise ValueError("fused launches require one common graph")
     states = [_MemberState(ix) for ix in indexes]
-    DRIVER_RUNS["fused"] += len(states)
+    trace.count(DRIVER + "fused", len(states))
     k1max = max(ix.k for ix in indexes) + 1
     mfm = _next_pow2(max(int(st.dev.dst.shape[0]) for st in states))
 
+    # each round is spanned phase by phase (pop, pack, tables, then
+    # dispatch, sync and split per launch, tail), so the phases cover it
     while True:
-        active = [st for st in states if st.result is None]
-        if not active:
-            break
-        if deadline is not None and clock.expired(deadline):
+        with trace.span("pathenum.enum.pop"):
+            active = [st for st in states if st.result is None]
+            if not active:
+                break
+            if deadline is not None and clock.expired(deadline):
+                for st in active:
+                    st.finish(exhausted=False)
+                break
+            trace.count("pathenum.enum.rounds")
+            # pop one chunk per active member; the host zero-fanout
+            # shortcut (solo: _device_step returns None without a
+            # launch) keeps dead chunks out of the dispatch entirely
+            members: List[Tuple[_MemberState, np.ndarray, int,
+                                np.ndarray]] = []
             for st in active:
-                st.finish(exhausted=False)
-            break
-
-        # pop one chunk per active member; the host zero-fanout shortcut
-        # (solo: _device_step returns None without a launch) keeps dead
-        # chunks out of the dispatch entirely
-        members: List[Tuple[_MemberState, np.ndarray, int, np.ndarray]] = []
-        for st in active:
-            paths, depth = st.work.pop()
-            st.stats.chunks += 1
-            k = st.idx.k
-            last = paths[:, depth].astype(np.int64)
-            b = k - depth - 1
-            cnt = (st.idx.fwd_end[last, b] - st.idx.fwd_begin[last]) \
-                if b >= 0 else np.zeros(paths.shape[0], np.int64)
-            if int(cnt.sum()) == 0:
-                st.stats.invalid_partials += paths.shape[0]
-                if not st.work:
-                    st.finish(exhausted=True, canonical=True)
-                continue
-            members.append((st, paths, depth, cnt))
+                paths, depth = st.work.pop()
+                st.stats.chunks += 1
+                k = st.idx.k
+                last = paths[:, depth].astype(np.int64)
+                b = k - depth - 1
+                cnt = (st.idx.fwd_end[last, b] - st.idx.fwd_begin[last]) \
+                    if b >= 0 else np.zeros(paths.shape[0], np.int64)
+                if int(cnt.sum()) == 0:
+                    st.stats.invalid_partials += paths.shape[0]
+                    if not st.work:
+                        st.finish(exhausted=True, canonical=True)
+                    continue
+                members.append((st, paths, depth, cnt))
         if not members:
             continue
 
-        packed, ranks, cnts = [], [], []
-        for i, (st, paths, depth, cnt) in enumerate(members):
-            if paths.shape[1] < k1max:
-                paths = np.pad(paths,
-                               ((0, 0), (0, k1max - paths.shape[1])),
-                               constant_values=PAD)
-            packed.append(paths)
-            ranks.append(np.full(paths.shape[0], i, np.int32))
-            cnts.append(cnt)
-        packed_paths = np.concatenate(packed, axis=0)
-        rank = np.concatenate(ranks)
-        packed_cnt = np.concatenate(cnts)
+        with trace.span("pathenum.enum.pack"):
+            packed, ranks, cnts = [], [], []
+            for i, (st, paths, depth, cnt) in enumerate(members):
+                if paths.shape[1] < k1max:
+                    paths = np.pad(paths,
+                                   ((0, 0), (0, k1max - paths.shape[1])),
+                                   constant_values=PAD)
+                packed.append(paths)
+                ranks.append(np.full(paths.shape[0], i, np.int32))
+                cnts.append(cnt)
+            packed_paths = np.concatenate(packed, axis=0)
+            rank = np.concatenate(ranks)
+            packed_cnt = np.concatenate(cnts)
+            # the solo path's slot-budget segmentation, over the packed
+            # rows: a hub member splits the round into several dispatches
+            # exactly as it would have split its own solo chunk
+            segments = _fanout_segments(packed_cnt, DEVICE_SLOT_BUDGET)
 
-        m = _next_pow2(len(members))
-        tvec = np.full(m, -1, np.int32)
-        depthv = np.zeros(m, np.int32)
-        wantc = np.zeros(m, bool)
-        begin_parts: List[object] = []
-        endb_parts: List[object] = []
-        dst_parts: List[object] = []
-        for i, (st, _paths, depth, _cnt) in enumerate(members):
-            k = st.idx.k
-            tvec[i] = st.idx.t
-            depthv[i] = depth
-            wantc[i] = depth + 1 < k
-            begin_parts.append(st.dev.begin)
-            endb_parts.append(st.dev.end[:, k - depth - 1])
-            mf = int(st.dev.dst.shape[0])
-            dst_parts.append(jnp.pad(st.dev.dst, (0, mfm - mf),
-                                     constant_values=PAD)
-                             if mf < mfm else st.dev.dst)
-        zero_col = jnp.zeros((n,), jnp.int32)
-        pad_dst = jnp.full((mfm,), PAD, jnp.int32)
-        for _ in range(m - len(members)):
-            begin_parts.append(zero_col)
-            endb_parts.append(zero_col)
-            dst_parts.append(pad_dst)
-        begin_flat = jnp.concatenate(begin_parts)
-        endb_flat = jnp.concatenate(endb_parts)
-        dst_flat = jnp.concatenate(dst_parts)
+        with trace.span("pathenum.enum.tables"):
+            m = _next_pow2(len(members))
+            tvec = np.full(m, -1, np.int32)
+            depthv = np.zeros(m, np.int32)
+            wantc = np.zeros(m, bool)
+            begin_parts: List[object] = []
+            endb_parts: List[object] = []
+            dst_parts: List[object] = []
+            for i, (st, _paths, depth, _cnt) in enumerate(members):
+                k = st.idx.k
+                tvec[i] = st.idx.t
+                depthv[i] = depth
+                wantc[i] = depth + 1 < k
+                begin_parts.append(st.dev.begin)
+                endb_parts.append(st.dev.end[:, k - depth - 1])
+                mf = int(st.dev.dst.shape[0])
+                dst_parts.append(jnp.pad(st.dev.dst, (0, mfm - mf),
+                                         constant_values=PAD)
+                                 if mf < mfm else st.dev.dst)
+            zero_col = jnp.zeros((n,), jnp.int32)
+            pad_dst = jnp.full((mfm,), PAD, jnp.int32)
+            for _ in range(m - len(members)):
+                begin_parts.append(zero_col)
+                endb_parts.append(zero_col)
+                dst_parts.append(pad_dst)
+            begin_flat = jnp.concatenate(begin_parts)
+            endb_flat = jnp.concatenate(endb_parts)
+            dst_flat = jnp.concatenate(dst_parts)
+            trace.count("pathenum.enum.table_bytes",
+                        begin_flat.nbytes + endb_flat.nbytes
+                        + dst_flat.nbytes)
 
-        # the solo path's slot-budget segmentation, over the packed rows:
-        # a hub member splits the round into several dispatches exactly
-        # as it would have split its own solo chunk
         emit_parts: List[List[np.ndarray]] = [[] for _ in members]
         cont_parts: List[List[np.ndarray]] = [[] for _ in members]
-        for lo, hi in _fanout_segments(packed_cnt, DEVICE_SLOT_BUDGET):
-            emit_rows, cont_rows, n_emit_m, n_cont_m, counters = \
-                kops.frontier_expand_fused(
+        for lo, hi in segments:
+            with trace.span("pathenum.enum.dispatch"):
+                out = kops.frontier_expand_fused(
                     packed_paths[lo:hi], rank[lo:hi], tvec, depthv,
                     begin_flat, endb_flat, dst_flat, wantc,
                     max_deg=max(int(packed_cnt[lo:hi].max()), 1))
-            ne_m = np.asarray(n_emit_m).astype(np.int64)
-            nc_m = np.asarray(n_cont_m).astype(np.int64)
-            ctr = np.asarray(counters)
-            e_lo = np.concatenate([[0], np.cumsum(ne_m)[:-1]])
-            c_lo = np.concatenate([[0], np.cumsum(nc_m)[:-1]])
-            emit_np = np.asarray(emit_rows)
-            cont_np = np.asarray(cont_rows)
-            for i, (st, _paths, _depth, _cnt) in enumerate(members):
-                st.stats.edges_accessed += int(ctr[i, 0])
-                st.stats.partials_generated += int(ctr[i, 1])
-                st.stats.invalid_partials += int(ctr[i, 2])
-                w = st.idx.k + 1
-                if ne_m[i]:
-                    emit_parts[i].append(
-                        emit_np[e_lo[i]:e_lo[i] + ne_m[i], :w])
-                if nc_m[i]:
-                    cont_parts[i].append(
-                        cont_np[c_lo[i]:c_lo[i] + nc_m[i], :w])
+            with trace.span("pathenum.enum.sync"):
+                emit_np, cont_np, ne_m, nc_m, ctr = (np.asarray(a)
+                                                     for a in out)
+            # the launch copies back whole padded rectangles; only the
+            # first n_emit + n_cont rows of them are live
+            live_rows = int(ne_m.sum()) + int(nc_m.sum())
+            _count_d2h(sum(a.nbytes for a in (emit_np, cont_np, ne_m, nc_m,
+                                              ctr)),
+                       live=live_rows * emit_np.shape[1] * 4
+                       + ne_m.nbytes + nc_m.nbytes + ctr.nbytes)
+            with trace.span("pathenum.enum.split"):
+                ne_m = ne_m.astype(np.int64)
+                nc_m = nc_m.astype(np.int64)
+                e_lo = np.concatenate([[0], np.cumsum(ne_m)[:-1]])
+                c_lo = np.concatenate([[0], np.cumsum(nc_m)[:-1]])
+                for i, (st, _paths, _depth, _cnt) in enumerate(members):
+                    st.stats.edges_accessed += int(ctr[i, 0])
+                    st.stats.partials_generated += int(ctr[i, 1])
+                    st.stats.invalid_partials += int(ctr[i, 2])
+                    w = st.idx.k + 1
+                    if ne_m[i]:
+                        emit_parts[i].append(
+                            emit_np[e_lo[i]:e_lo[i] + ne_m[i], :w])
+                    if nc_m[i]:
+                        cont_parts[i].append(
+                            cont_np[c_lo[i]:c_lo[i] + nc_m[i], :w])
 
         # per-member driver tail — the exact _drive emit/push sequence
-        for i, (st, _paths, depth, _cnt) in enumerate(members):
-            if emit_parts[i]:
-                emit_cat = np.concatenate(emit_parts[i], axis=0)
-                st.count += emit_cat.shape[0]
-                st.stats.results += emit_cat.shape[0]
-                if not count_only:
-                    st.out_paths.append(emit_cat)
-                    st.out_lens.append(np.full(emit_cat.shape[0],
-                                               depth + 1, np.int32))
-                if first_n is not None and st.count >= first_n:
-                    st.count = _trim_to_first_n(
-                        st.out_paths, st.out_lens, st.count, first_n,
-                        count_only, st.stats)
-                    st.finish(exhausted=False)
-                    continue
-            if cont_parts[i]:
-                cont_cat = np.concatenate(cont_parts[i], axis=0)
-                pieces = range(0, cont_cat.shape[0], chunk_size)
-                for piece in reversed(list(pieces)):
-                    st.work.append(
-                        (cont_cat[piece:piece + chunk_size], depth + 1))
-            if not st.work:
-                st.finish(exhausted=True, canonical=True)
+        with trace.span("pathenum.enum.tail"):
+            for i, (st, _paths, depth, _cnt) in enumerate(members):
+                if emit_parts[i]:
+                    emit_cat = np.concatenate(emit_parts[i], axis=0)
+                    st.count += emit_cat.shape[0]
+                    st.stats.results += emit_cat.shape[0]
+                    if not count_only:
+                        st.out_paths.append(emit_cat)
+                        st.out_lens.append(np.full(emit_cat.shape[0],
+                                                   depth + 1, np.int32))
+                    if first_n is not None and st.count >= first_n:
+                        st.count = _trim_to_first_n(
+                            st.out_paths, st.out_lens, st.count, first_n,
+                            count_only, st.stats)
+                        st.finish(exhausted=False)
+                        continue
+                if cont_parts[i]:
+                    cont_cat = np.concatenate(cont_parts[i], axis=0)
+                    pieces = range(0, cont_cat.shape[0], chunk_size)
+                    for piece in reversed(list(pieces)):
+                        st.work.append(
+                            (cont_cat[piece:piece + chunk_size], depth + 1))
+                if not st.work:
+                    st.finish(exhausted=True, canonical=True)
 
     return [st.result for st in states]  # type: ignore[misc]

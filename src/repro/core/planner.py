@@ -16,6 +16,7 @@ import dataclasses
 import time
 from typing import Optional
 
+from .. import trace
 from . import estimator as est
 from .index import LightweightIndex
 from .join import hop_count_dp
@@ -44,26 +45,24 @@ def plan_query(index: LightweightIndex, tau: float = DEFAULT_TAU,
     is bit-identical to the host build (it promotes itself to the host
     on f32 overflow), so the *plan* never depends on the backend, only
     the derivation cost does.  The O(k²) preliminary estimate is host
-    scalar math always."""
-    t0 = time.perf_counter()
-    t_hat = est.preliminary_estimate(index)
-    if t_hat <= tau:
+    scalar math always.  ``optimize_seconds`` is the ``pathenum.plan``
+    span's duration."""
+    with trace.span("pathenum.plan") as sp:
+        t_hat = est.preliminary_estimate(index)
+        dp = hop_count_dp(index, backend) if t_hat > tau else None
+    if dp is None:
         return Plan(method="dfs", cut=None, preliminary=t_hat,
-                    used_full_estimator=False,
-                    optimize_seconds=time.perf_counter() - t0)
-
-    dp = hop_count_dp(index, backend)
-    cut = dp.cut
+                    used_full_estimator=False, optimize_seconds=sp.seconds)
     # a cut at the boundary degenerates to the left-deep plan
+    cut = dp.cut
     if cut <= 0 or cut >= index.k or dp.t_dfs <= dp.t_join:
         return Plan(method="dfs", cut=None, preliminary=t_hat,
                     used_full_estimator=True, t_dfs=dp.t_dfs,
                     t_join=dp.t_join, est_results=dp.q_total, dp=dp,
-                    optimize_seconds=time.perf_counter() - t0)
+                    optimize_seconds=sp.seconds)
     return Plan(method="join", cut=cut, preliminary=t_hat,
                 used_full_estimator=True, t_dfs=dp.t_dfs, t_join=dp.t_join,
-                est_results=dp.q_total, dp=dp,
-                optimize_seconds=time.perf_counter() - t0)
+                est_results=dp.q_total, dp=dp, optimize_seconds=sp.seconds)
 
 
 def calibrate_tau(graph, queries, k: int = 6, start: float = 10.0,

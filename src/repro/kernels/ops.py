@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import trace
 from . import ref
 from .decode_attention import decode_attention as _decode_pallas
 from .flash_attention import flash_attention as _flash_pallas
@@ -27,26 +28,35 @@ from .frontier_expand import frontier_fused_masks as _frontier_fused_pallas
 from .semiring_spmm import BLOCK, counting_spmm as _counting_pallas
 from .semiring_spmm import minplus_spmv as _minplus_pallas
 
-# Monotone counts of frontier-expansion device dispatches (single-query,
-# fused and deque-round launches alike), keyed by the launch's pow2
-# fan-out bucket (the kernels' ``max_deg``).  The fused-launch and deque
-# tests assert on deltas of the total — it is the ground truth for "one
-# dispatch per expansion round" (DESIGN.md §9).
-_dispatches: dict[int, int] = {}
+# Frontier-expansion device dispatches (single-query, fused and
+# deque-round launches alike) are counted in the ``repro.trace`` tally,
+# one counter per pow2 fan-out bucket (the kernels' ``max_deg``).  The
+# fused-launch and deque tests assert on deltas of the total — it is the
+# ground truth for "one dispatch per expansion round" (DESIGN.md §9).
+DISPATCHES = "pathenum.enum.dispatches."
 
 
 def device_dispatch_count() -> int:
     """Total frontier-expansion kernel dispatches since process start."""
-    return sum(_dispatches.values())
+    return sum(trace.counters(DISPATCHES).values())
 
 
 def device_dispatch_fanouts() -> dict[int, int]:
     """Dispatches since process start per pow2 fan-out bucket (a copy)."""
-    return dict(_dispatches)
+    return {int(name[len(DISPATCHES):]): v
+            for name, v in trace.counters(DISPATCHES).items()}
 
 
-def _count_dispatch(max_deg: int) -> None:
-    _dispatches[max_deg] = _dispatches.get(max_deg, 0) + 1
+def _count_launch(max_deg: int, slots: int, *host: object) -> None:
+    """Count one frontier launch: its dispatch in its fan-out bucket, the
+    candidate slots its padded rectangle covers (0 where the host learns
+    them only from the launch's outputs) and the bytes of the host
+    arrays it sends to the device."""
+    trace.count(f"{DISPATCHES}{max_deg}")
+    if slots:
+        trace.count("pathenum.enum.slots", slots)
+    trace.count("pathenum.xfer.h2d_bytes",
+                sum(a.nbytes for a in host if isinstance(a, np.ndarray)))
 
 
 def _interpret() -> bool:
@@ -244,12 +254,13 @@ def frontier_expand(
     C = _next_pow2(max(rows, 8))
     if C != rows:
         paths = np.pad(paths, ((0, C - rows), (0, 0)), constant_values=PAD)
-    meta = jnp.asarray([depth, t], jnp.int32)
+    meta = np.asarray([depth, t], np.int32)
     max_deg = _next_pow2(max_deg)
-    _count_dispatch(max_deg)
+    _count_launch(max_deg, C * max_deg, paths, fwd_begin, fwd_end, fwd_dst,
+                  meta)
     return _frontier_expand_jit(
         jnp.asarray(paths), jnp.asarray(fwd_begin), jnp.asarray(fwd_end),
-        jnp.asarray(fwd_dst), meta, max_deg=max_deg,
+        jnp.asarray(fwd_dst), jnp.asarray(meta), max_deg=max_deg,
         interpret=_interpret(), use_ref=not _enabled(),
         want_cont=want_cont)
 
@@ -340,17 +351,20 @@ def frontier_expand_fused(
     paths = np.asarray(paths, dtype=np.int32)
     rows, _k1 = paths.shape
     assert max_deg >= 1, "zero-fanout chunks never reach the device"
+    rank = np.asarray(rank, np.int32)
     C = _next_pow2(max(rows, 8))
     if C != rows:
         paths = np.pad(paths, ((0, C - rows), (0, 0)), constant_values=PAD)
-        rank = np.pad(np.asarray(rank, np.int32), (0, C - rows))
+        rank = np.pad(rank, (0, C - rows))
+    tvec = np.asarray(tvec, np.int32)
+    depthv = np.asarray(depthv, np.int32)
+    wantc = np.asarray(wantc, bool)
     max_deg = _next_pow2(max_deg)
-    _count_dispatch(max_deg)
+    _count_launch(max_deg, C * max_deg, paths, rank, tvec, depthv, wantc)
     return _frontier_fused_jit(
-        jnp.asarray(paths), jnp.asarray(rank, dtype=jnp.int32),
-        jnp.asarray(tvec, dtype=jnp.int32),
-        jnp.asarray(depthv, dtype=jnp.int32), begin, endb, dst,
-        jnp.asarray(wantc, dtype=bool), max_deg=max_deg,
+        jnp.asarray(paths), jnp.asarray(rank), jnp.asarray(tvec),
+        jnp.asarray(depthv), begin, endb, dst,
+        jnp.asarray(wantc), max_deg=max_deg,
         interpret=_interpret(), use_ref=not _enabled())
 
 
@@ -408,8 +422,10 @@ def frontier_deque_init(root: np.ndarray, *, cfg: DequeConfig
                         ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray,
                                    jnp.ndarray, jnp.ndarray]:
     """Fresh deque state holding one root chunk (the (k+1,) root row)."""
+    root = np.asarray(root, np.int32)
+    trace.count("pathenum.xfer.h2d_bytes", root.nbytes)
     arena = jnp.full((cfg.arena_rows, cfg.k1), PAD, jnp.int32)
-    arena = arena.at[0].set(jnp.asarray(root, jnp.int32))
+    arena = arena.at[0].set(jnp.asarray(root))
     meta_depth = jnp.zeros((cfg.max_chunks + cfg.max_pieces,), jnp.int32)
     meta_len = meta_depth.at[0].set(1)
     return arena, meta_depth, meta_len, jnp.int32(1), jnp.int32(1)
@@ -535,11 +551,14 @@ def frontier_deque_round(
     stall: the caller rebuilds its host work list from ``arena[:top]``
     and the bottom ``n_chunks`` meta slots and resumes the host-looped
     driver.  ``REPRO_PALLAS=off`` routes the mask stage to the ref
-    oracle; counted as one device dispatch per round.
+    oracle; counted as one device dispatch per round.  The round's
+    candidate slots (``pops × cfg.cap``) are known only once the caller
+    reads ``pops``, so the caller counts them.
     """
-    _count_dispatch(cfg.max_deg)
+    tt = np.asarray(t, np.int32)
+    _count_launch(cfg.max_deg, 0, tt)
     return _deque_round_jit(arena, meta_depth, meta_len, top, n_chunks,
-                            begin, end, dst, jnp.asarray(t, jnp.int32),
+                            begin, end, dst, jnp.asarray(tt),
                             cfg=cfg, interpret=_interpret(),
                             use_ref=not _enabled())
 

@@ -57,6 +57,7 @@ from typing import Union
 if TYPE_CHECKING:  # deferred: metrics imports this module at runtime
     from .metrics import MetricsSnapshot
 
+from .. import trace
 from ..core import clock
 from ..core.batch import BatchOutput, BatchPathEnum, DEFAULT_GRAPH_ID
 from ..core.enumerate import EnumStats
@@ -451,11 +452,12 @@ class AsyncHcPEServer:
                 # The wait is interruptible: stop() sets _stop_evt, so a
                 # drain never sits out the rest of a batching window — no
                 # new admissions can arrive to fill it anyway
-                try:
-                    await asyncio.wait_for(self._stop_evt.wait(),
-                                           self.batch_window_ms / 1e3)
-                except asyncio.TimeoutError:
-                    pass
+                with trace.span("pathenum.frontend.window"):
+                    try:
+                        await asyncio.wait_for(self._stop_evt.wait(),
+                                               self.batch_window_ms / 1e3)
+                    except asyncio.TimeoutError:
+                        pass
             while self._pending:
                 await self._serve_group(self._pop_edf_group())
 
@@ -507,29 +509,32 @@ class AsyncHcPEServer:
                 self._settle(p)
             return
         done = clock.now()
-        self._outputs.append(out)
-        self.enum_totals.merge(out.enum_stats)
-        for p, item in zip(group, out.items):
-            if p.future.done():      # submit cancelled (e.g. wait_for timeout)
-                self.stats.cancelled += 1
-                self._settle(p)      # — drop the response, keep the scheduler
-                continue
-            resp = response_from_item(p.req, item)
-            resp.queue_ms = (dispatched - p.enqueued_at) * 1e3
-            resp.service_ms = (done - dispatched) * 1e3
-            resp.total_ms = (done - p.enqueued_at) * 1e3
-            if p.deadline_at is not None:
-                resp.slo_met = done <= p.deadline_at
-                if resp.slo_met:
-                    self.stats.slo_met += 1
-                else:
-                    self.stats.slo_missed += 1
-            self.stats.completed += 1
-            self.stats.queue_ms_total += resp.queue_ms
-            self.stats.service_ms_total += resp.service_ms
-            self.stats.total_ms_total += resp.total_ms
-            p.future.set_result(resp)
-            self._settle(p)
+        with trace.span("pathenum.frontend.respond"):
+            self._outputs.append(out)
+            self.enum_totals.merge(out.enum_stats)
+            for p, item in zip(group, out.items):
+                if p.future.done():
+                    # submit cancelled (e.g. wait_for timeout): drop the
+                    # response, keep the scheduler
+                    self.stats.cancelled += 1
+                    self._settle(p)
+                    continue
+                resp = response_from_item(p.req, item)
+                resp.queue_ms = (dispatched - p.enqueued_at) * 1e3
+                resp.service_ms = (done - dispatched) * 1e3
+                resp.total_ms = (done - p.enqueued_at) * 1e3
+                if p.deadline_at is not None:
+                    resp.slo_met = done <= p.deadline_at
+                    if resp.slo_met:
+                        self.stats.slo_met += 1
+                    else:
+                        self.stats.slo_missed += 1
+                self.stats.completed += 1
+                self.stats.queue_ms_total += resp.queue_ms
+                self.stats.service_ms_total += resp.service_ms
+                self.stats.total_ms_total += resp.total_ms
+                p.future.set_result(resp)
+                self._settle(p)
 
     def _reject_group_mid_flight(self, group: List[_Pending],
                                  status: str) -> None:

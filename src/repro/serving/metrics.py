@@ -33,6 +33,7 @@ import json
 import time
 from typing import Dict, List, Optional, Union
 
+from .. import trace
 from ..core.batch import CacheStats
 from ..core.enumerate import EnumStats
 from .async_server import AsyncHcPEServer, AsyncServeStats
@@ -63,9 +64,10 @@ class TenantMetrics:
 class MetricsSnapshot:
     """A point-in-time value copy of every operational counter a serving
     stack exposes (DESIGN.md §12): global + per-tenant index-cache
-    stats, merged Fig.-6 enumeration totals, and — for the async
-    front-end — admission/SLO/latency counters and queue depth.
-    ``serve`` is None for the sync server (it has no admission plane).
+    stats, merged Fig.-6 enumeration totals, the process's program
+    spans and counters (``repro.trace``), and — for the async front-end
+    — admission/SLO/latency counters and queue depth.  ``serve`` is None
+    for the sync server (it has no admission plane).
     """
     captured_at: float             # time.time() at capture
     cache: CacheStats              # global engine cache counters
@@ -75,6 +77,10 @@ class MetricsSnapshot:
     tenants: Dict[str, TenantMetrics]
     serve: Optional[AsyncServeStats] = None
     queue_depth: int = 0
+    # the process's span-and-counter tally (``repro.trace.snapshot``):
+    # {"spans": {name: [seconds, calls]}, "counters": {name: value}}
+    program: trace.Snapshot = dataclasses.field(
+        default_factory=lambda: {"spans": {}, "counters": {}})
 
     def to_dict(self) -> Dict[str, object]:
         """The snapshot as plain nested dicts/lists — ``json.loads
@@ -91,7 +97,11 @@ class MetricsSnapshot:
         one ``# TYPE`` header each, tenants as ``graph_id`` labels.
         Counters export as ``*_total``; occupancy, quotas, versions and
         queue depth as gauges (an unset quota exports no sample rather
-        than a fake bound)."""
+        than a fake bound).  Program spans export as
+        ``pathenum_span_seconds_total`` / ``pathenum_span_calls_total``
+        with a ``span`` label, and each program counter
+        ``pathenum.<name>`` as ``pathenum_<name>_total`` (dots become
+        underscores)."""
         lines: List[str] = []
 
         def counter(name: str, value: Union[int, float],
@@ -134,10 +144,19 @@ class MetricsSnapshot:
                     gauge("pathenum_tenant_max_pending", tm.max_pending, gid)
             if self.serve is not None:
                 gauge("pathenum_tenant_inflight", tm.inflight, gid)
+        for name, (secs, calls) in self.program["spans"].items():
+            self._sample(lines, "pathenum_span_seconds_total", "counter",
+                         secs, name, "span")
+            self._sample(lines, "pathenum_span_calls_total", "counter",
+                         calls, name, "span")
+        for name, value in self.program["counters"].items():
+            family = name.replace(trace.PREFIX, "", 1).replace(".", "_")
+            counter(f"pathenum_{family}_total", value)
         return "\n".join(lines) + "\n"
 
     def _sample(self, lines: List[str], name: str, kind: str,
-                value: Union[int, float], label: Optional[str]) -> None:
+                value: Union[int, float], label: Optional[str],
+                label_key: str = "graph_id") -> None:
         header = f"# TYPE {name} {kind}"
         if header not in lines:
             lines.append(header)
@@ -146,7 +165,7 @@ class MetricsSnapshot:
         else:
             esc = (label.replace("\\", r"\\").replace('"', r"\"")
                    .replace("\n", r"\n"))
-            lines.append(f'{name}{{graph_id="{esc}"}} {value}')
+            lines.append(f'{name}{{{label_key}="{esc}"}} {value}')
 
     def violations(self) -> List[str]:
         """Re-check the counter identities the serving stack promises
@@ -192,8 +211,9 @@ def snapshot(server: Union[HcPEServer, AsyncHcPEServer]) -> MetricsSnapshot:
     """Capture a ``MetricsSnapshot`` from either HcPE front-end
     (DESIGN.md §12).
 
-    Reads the server's registry, engine cache and — on the async
-    front-end — its ``AsyncServeStats``; every counter lands in the
+    Reads the server's registry, engine cache, the process's program
+    tally (``repro.trace.snapshot``) and — on the async front-end — its
+    ``AsyncServeStats``; every counter lands in the
     snapshot as a value copy (``CacheStats.snapshot`` /
     ``dataclasses.replace``), so later traffic never mutates captured
     evidence.  Tenants are the union of registered ids and ids with
@@ -230,6 +250,7 @@ def snapshot(server: Union[HcPEServer, AsyncHcPEServer]) -> MetricsSnapshot:
     enum_totals = EnumStats()
     enum_totals.merge(server.enum_totals)
     return MetricsSnapshot(
+        program=trace.snapshot(),
         captured_at=time.time(),
         cache=cache.stats.snapshot(),
         cache_entries=len(cache),
