@@ -5,9 +5,13 @@ query's chunk walk issued its own sequence of kernel dispatches, so an
 async micro-batch of N device-eligible queries paid N dispatch streams.
 This driver packs the frontier walks of many queries into *fused
 launches*: every expansion round pops one chunk from each active
-query's LIFO deque, tags the rows with the query's member rank, and
-expands them all through ONE ``ops.frontier_expand_fused`` dispatch
-(tests/test_fused_launch.py asserts the launch count).
+query's LIFO deque, tags the rows with the query's slot, and expands
+them all through ONE ``ops.frontier_expand_fused`` dispatch
+(tests/test_fused_launch.py asserts the launch count).  The members'
+offset tables and adjacency slabs are stacked once per run
+(``ops.fused_tables``), each member at a fixed slot; a round sends only
+its rows and per-slot vectors, and its launch selects each member's
+budget column itself.
 
 Per-query semantics are `core.enumerate._drive`'s, replicated exactly:
 
@@ -87,7 +91,6 @@ def enumerate_fused_device(
     ``exhausted=False``.
     """
     from ..kernels import ops as kops   # lazy: pallas only on this path
-    import jax.numpy as jnp
     if not indexes:
         return []
     n = indexes[0].n
@@ -96,26 +99,36 @@ def enumerate_fused_device(
     states = [_MemberState(ix) for ix in indexes]
     trace.count(DRIVER + "fused", len(states))
     k1max = max(ix.k for ix in indexes) + 1
-    mfm = _next_pow2(max(int(st.dev.dst.shape[0]) for st in states))
+    # member i keeps slot i for the whole run: the tables are built
+    # once, and a finished member's slot stays, with no row pointing at it
+    slots = _next_pow2(len(states))
+    with trace.span("pathenum.enum.tables"):
+        begin_all, end_all, dst_all = kops.fused_tables(
+            [st.dev.begin for st in states], [st.dev.end for st in states],
+            [st.dev.dst for st in states], slots=slots)
+        trace.count("pathenum.enum.table_builds")
+        trace.count("pathenum.enum.table_bytes",
+                    begin_all.nbytes + end_all.nbytes + dst_all.nbytes)
 
-    # each round is spanned phase by phase (pop, pack, tables, then
-    # dispatch, sync and split per launch, tail), so the phases cover it
+    # each round is spanned phase by phase (pop, pack, then dispatch,
+    # sync and split per launch, tail), so the phases cover it
     while True:
         with trace.span("pathenum.enum.pop"):
-            active = [st for st in states if st.result is None]
+            active = [(slot, st) for slot, st in enumerate(states)
+                      if st.result is None]
             if not active:
                 break
             if deadline is not None and clock.expired(deadline):
-                for st in active:
+                for _slot, st in active:
                     st.finish(exhausted=False)
                 break
             trace.count("pathenum.enum.rounds")
             # pop one chunk per active member; the host zero-fanout
             # shortcut (solo: _device_step returns None without a
             # launch) keeps dead chunks out of the dispatch entirely
-            members: List[Tuple[_MemberState, np.ndarray, int,
+            members: List[Tuple[int, _MemberState, np.ndarray, int,
                                 np.ndarray]] = []
-            for st in active:
+            for slot, st in active:
                 paths, depth = st.work.pop()
                 st.stats.chunks += 1
                 k = st.idx.k
@@ -128,19 +141,28 @@ def enumerate_fused_device(
                     if not st.work:
                         st.finish(exhausted=True, canonical=True)
                     continue
-                members.append((st, paths, depth, cnt))
+                members.append((slot, st, paths, depth, cnt))
         if not members:
             continue
 
         with trace.span("pathenum.enum.pack"):
+            tvec = np.full(slots, -1, np.int32)
+            depthv = np.zeros(slots, np.int32)
+            bvec = np.zeros(slots, np.int32)
+            wantc = np.zeros(slots, bool)
             packed, ranks, cnts = [], [], []
-            for i, (st, paths, depth, cnt) in enumerate(members):
+            for slot, st, paths, depth, cnt in members:
+                k = st.idx.k
+                tvec[slot] = st.idx.t
+                depthv[slot] = depth
+                bvec[slot] = k - depth - 1
+                wantc[slot] = depth + 1 < k
                 if paths.shape[1] < k1max:
                     paths = np.pad(paths,
                                    ((0, 0), (0, k1max - paths.shape[1])),
                                    constant_values=PAD)
                 packed.append(paths)
-                ranks.append(np.full(paths.shape[0], i, np.int32))
+                ranks.append(np.full(paths.shape[0], slot, np.int32))
                 cnts.append(cnt)
             packed_paths = np.concatenate(packed, axis=0)
             rank = np.concatenate(ranks)
@@ -150,45 +172,13 @@ def enumerate_fused_device(
             # exactly as it would have split its own solo chunk
             segments = _fanout_segments(packed_cnt, DEVICE_SLOT_BUDGET)
 
-        with trace.span("pathenum.enum.tables"):
-            m = _next_pow2(len(members))
-            tvec = np.full(m, -1, np.int32)
-            depthv = np.zeros(m, np.int32)
-            wantc = np.zeros(m, bool)
-            begin_parts: List[object] = []
-            endb_parts: List[object] = []
-            dst_parts: List[object] = []
-            for i, (st, _paths, depth, _cnt) in enumerate(members):
-                k = st.idx.k
-                tvec[i] = st.idx.t
-                depthv[i] = depth
-                wantc[i] = depth + 1 < k
-                begin_parts.append(st.dev.begin)
-                endb_parts.append(st.dev.end[:, k - depth - 1])
-                mf = int(st.dev.dst.shape[0])
-                dst_parts.append(jnp.pad(st.dev.dst, (0, mfm - mf),
-                                         constant_values=PAD)
-                                 if mf < mfm else st.dev.dst)
-            zero_col = jnp.zeros((n,), jnp.int32)
-            pad_dst = jnp.full((mfm,), PAD, jnp.int32)
-            for _ in range(m - len(members)):
-                begin_parts.append(zero_col)
-                endb_parts.append(zero_col)
-                dst_parts.append(pad_dst)
-            begin_flat = jnp.concatenate(begin_parts)
-            endb_flat = jnp.concatenate(endb_parts)
-            dst_flat = jnp.concatenate(dst_parts)
-            trace.count("pathenum.enum.table_bytes",
-                        begin_flat.nbytes + endb_flat.nbytes
-                        + dst_flat.nbytes)
-
         emit_parts: List[List[np.ndarray]] = [[] for _ in members]
         cont_parts: List[List[np.ndarray]] = [[] for _ in members]
         for lo, hi in segments:
             with trace.span("pathenum.enum.dispatch"):
                 out = kops.frontier_expand_fused(
                     packed_paths[lo:hi], rank[lo:hi], tvec, depthv,
-                    begin_flat, endb_flat, dst_flat, wantc,
+                    begin_all, end_all, bvec, dst_all, wantc,
                     max_deg=max(int(packed_cnt[lo:hi].max()), 1))
             with trace.span("pathenum.enum.sync"):
                 emit_np, cont_np, ne_m, nc_m, ctr = (np.asarray(a)
@@ -205,21 +195,21 @@ def enumerate_fused_device(
                 nc_m = nc_m.astype(np.int64)
                 e_lo = np.concatenate([[0], np.cumsum(ne_m)[:-1]])
                 c_lo = np.concatenate([[0], np.cumsum(nc_m)[:-1]])
-                for i, (st, _paths, _depth, _cnt) in enumerate(members):
-                    st.stats.edges_accessed += int(ctr[i, 0])
-                    st.stats.partials_generated += int(ctr[i, 1])
-                    st.stats.invalid_partials += int(ctr[i, 2])
+                for i, (slot, st, *_) in enumerate(members):
+                    st.stats.edges_accessed += int(ctr[slot, 0])
+                    st.stats.partials_generated += int(ctr[slot, 1])
+                    st.stats.invalid_partials += int(ctr[slot, 2])
                     w = st.idx.k + 1
-                    if ne_m[i]:
+                    if ne_m[slot]:
                         emit_parts[i].append(
-                            emit_np[e_lo[i]:e_lo[i] + ne_m[i], :w])
-                    if nc_m[i]:
+                            emit_np[e_lo[slot]:e_lo[slot] + ne_m[slot], :w])
+                    if nc_m[slot]:
                         cont_parts[i].append(
-                            cont_np[c_lo[i]:c_lo[i] + nc_m[i], :w])
+                            cont_np[c_lo[slot]:c_lo[slot] + nc_m[slot], :w])
 
         # per-member driver tail — the exact _drive emit/push sequence
         with trace.span("pathenum.enum.tail"):
-            for i, (st, _paths, depth, _cnt) in enumerate(members):
+            for i, (_slot, st, _paths, depth, _cnt) in enumerate(members):
                 if emit_parts[i]:
                     emit_cat = np.concatenate(emit_parts[i], axis=0)
                     st.count += emit_cat.shape[0]

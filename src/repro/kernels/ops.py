@@ -282,23 +282,27 @@ def _children_fused(paths: jnp.ndarray, vflat: jnp.ndarray,
                    static_argnames=("max_deg", "interpret", "use_ref"))
 def _frontier_fused_jit(
         paths: jnp.ndarray, rank: jnp.ndarray, tvec: jnp.ndarray,
-        depthv: jnp.ndarray, begin: jnp.ndarray, endb: jnp.ndarray,
-        dst: jnp.ndarray, wantc: jnp.ndarray, *, max_deg: int,
-        interpret: bool, use_ref: bool
+        depthv: jnp.ndarray, begin: jnp.ndarray, end_all: jnp.ndarray,
+        bvec: jnp.ndarray, dst: jnp.ndarray, wantc: jnp.ndarray, *,
+        max_deg: int, interpret: bool, use_ref: bool
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray,
            jnp.ndarray]:
-    """Fused masks (Pallas kernel or jnp ref) + compaction, one jit.
+    """Budget-column select, fused masks (Pallas kernel or jnp ref) and
+    compaction, one jit.
 
+    Each member's budget row ``end_all[slot, bvec[slot]]`` is gathered
+    here, in the launch's own program, so a round builds no tables.
     Compaction runs over the *flat* candidate order (row-major), and the
-    wrapper packs rows member-rank-ascending, so the compacted emit and
-    cont matrices are per-member contiguous segments in each member's
-    exact solo emission order — the host slices them apart with the
-    per-member counts.  Last-hop continue suppression happens HERE (the
-    ``wantc`` per-member mask), after the kernel: the kernel always
-    computes the full cont mask so dead-row and counter accounting
-    matches the single-query kernel bit-for-bit.
+    wrapper packs rows slot-ascending, so the compacted emit and cont
+    matrices are per-member contiguous segments in each member's exact
+    solo emission order — the host slices them apart with the per-slot
+    counts.  Last-hop continue suppression happens HERE (the ``wantc``
+    per-slot mask), after the kernel: the kernel always computes the
+    full cont mask so dead-row and counter accounting matches the
+    single-query kernel bit-for-bit.
     """
     m = tvec.shape[0]
+    endb = end_all[jnp.arange(m), bvec].reshape(-1)         # (m·n,)
     if use_ref:
         vnew, emit, cont, counters = ref.frontier_fused_masks_ref(
             paths, rank, tvec, depthv, begin, endb, dst, max_deg, PAD)
@@ -324,28 +328,33 @@ def _frontier_fused_jit(
 
 def frontier_expand_fused(
         paths: np.ndarray, rank: np.ndarray, tvec: np.ndarray,
-        depthv: np.ndarray, begin: jnp.ndarray, endb: jnp.ndarray,
-        dst: jnp.ndarray, wantc: np.ndarray, *, max_deg: int
+        depthv: np.ndarray, begin: jnp.ndarray, end_all: jnp.ndarray,
+        bvec: np.ndarray, dst: jnp.ndarray, wantc: np.ndarray, *,
+        max_deg: int
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray,
            jnp.ndarray]:
     """One fused IDX-DFS hop for chunks of many queries (DESIGN.md §9).
 
-    ``paths`` (rows, k1max) int32 packs one chunk per member, rows in
-    ascending member order, each member's rows at its own common depth
-    (columns past a member's own k+1 stay PAD); ``rank`` (rows,) int32
-    tags each row's member; ``tvec``/``depthv`` (m,) int32 carry each
-    member's target and chunk depth; ``begin``/``endb`` (m·n,) int32 are
-    the flattened per-member offset tables (``endb`` pre-sliced to each
-    member's budget column b = k − depth − 1); ``dst`` (m·mfm,) int32
-    the flattened adjacency slabs (PAD-padded to the common ``mfm``);
-    ``wantc`` (m,) bool is per-member ``want_cont`` (False on a member's
-    last hop — suppression happens after the kernel so counters still
-    see the candidates, exactly like the single-query path).
+    A fused run gives each member a fixed *slot* in ``[0, m)`` and
+    builds its tables once (``fused_tables``).  ``paths`` (rows, k1max)
+    int32 packs one chunk per live member, rows in ascending slot order,
+    each member's rows at its own common depth (columns past a member's
+    own k+1 stay PAD); ``rank`` (rows,) int32 tags each row with its
+    member's slot; ``tvec``/``depthv`` (m,) int32 carry each slot's
+    target and chunk depth; ``begin`` (m·n,) int32 is the flattened
+    per-slot ``fwd_begin``; ``end_all`` (m, k1max, n) int32 holds each
+    slot's ``fwd_end`` with the budget as its middle axis, and ``bvec``
+    (m,) int32 each slot's budget b = k − depth − 1 this round (0 for a
+    slot no row points at), selected inside the launch; ``dst`` (m·mfm,)
+    int32 the flattened adjacency slabs (PAD-padded to the common
+    ``mfm``); ``wantc`` (m,) bool is per-slot ``want_cont`` (False on a
+    member's last hop — suppression happens after the kernel so counters
+    still see the candidates, exactly like the single-query path).
 
     Returns ``(emit_rows, cont_rows, n_emit_m, n_cont_m, counters)``:
-    emit/cont row matrices in flat order (member-contiguous — slice
-    member i's segment with the exclusive cumsum of ``n_emit_m`` /
-    ``n_cont_m``), and ``counters`` the (m, 4) per-member Fig.-6 rows.
+    emit/cont row matrices in flat order (slot-contiguous — slice slot
+    i's segment with the exclusive cumsum of ``n_emit_m`` /
+    ``n_cont_m``), and ``counters`` the (m, 4) per-slot Fig.-6 rows.
     All device-resident; one kernel dispatch per call.
     """
     paths = np.asarray(paths, dtype=np.int32)
@@ -358,14 +367,61 @@ def frontier_expand_fused(
         rank = np.pad(rank, (0, C - rows))
     tvec = np.asarray(tvec, np.int32)
     depthv = np.asarray(depthv, np.int32)
+    bvec = np.asarray(bvec, np.int32)
     wantc = np.asarray(wantc, bool)
     max_deg = _next_pow2(max_deg)
-    _count_launch(max_deg, C * max_deg, paths, rank, tvec, depthv, wantc)
+    _count_launch(max_deg, C * max_deg, paths, rank, tvec, depthv, bvec,
+                  wantc)
     return _frontier_fused_jit(
         jnp.asarray(paths), jnp.asarray(rank), jnp.asarray(tvec),
-        jnp.asarray(depthv), begin, endb, dst,
+        jnp.asarray(depthv), begin, end_all, jnp.asarray(bvec), dst,
         jnp.asarray(wantc), max_deg=max_deg,
         interpret=_interpret(), use_ref=not _enabled())
+
+
+@functools.partial(jax.jit, static_argnames=("slots",))
+def _fused_tables_jit(
+        begins: tuple[jnp.ndarray, ...], ends: tuple[jnp.ndarray, ...],
+        dsts: tuple[jnp.ndarray, ...], *, slots: int
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """`fused_tables`' one device program over equal-shaped members."""
+    n, k1 = ends[0].shape
+    pad = slots - len(begins)
+    begin = jnp.concatenate([*begins, jnp.zeros((pad * n,), jnp.int32)])
+    end = jnp.concatenate([jnp.stack([e.T for e in ends]),
+                           jnp.zeros((pad, k1, n), jnp.int32)])
+    dst = jnp.concatenate(
+        [*dsts, jnp.full((pad * dsts[0].shape[0],), PAD, jnp.int32)])
+    return begin, end, dst
+
+
+def fused_tables(
+        begins: list[jnp.ndarray], ends: list[jnp.ndarray],
+        dsts: list[jnp.ndarray], *, slots: int
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """A fused run's device tables, built once for all its rounds.
+
+    ``begins[i]`` (n,), ``ends[i]`` (n, k_i+1) and ``dsts[i]`` (mf_i,)
+    are member i's ``device_arrays``; member i takes slot i of ``slots``.
+    Returns ``(begin, end_all, dst)`` for ``frontier_expand_fused``:
+    begin (slots·n,), end_all (slots, k1max, n) with the vertex axis
+    minor, dst (slots·mfm,) with each slab PAD-padded to the largest
+    ``mfm``.  Slots past the last member hold zero offsets and PAD
+    slabs.  A member with a smaller k or a shorter slab is padded first
+    (columns past its own k+1 are never read: b ≤ k), so the one
+    stacking program compiles per (members, slots, n, k1max, mfm), not
+    per member set.
+    """
+    k1 = max(int(e.shape[1]) for e in ends)
+    mfm = max(int(d.shape[0]) for d in dsts)
+    ends = [e if e.shape[1] == k1 else jnp.pad(e, ((0, 0),
+                                                   (0, k1 - e.shape[1])))
+            for e in ends]
+    dsts = [d if d.shape[0] == mfm else jnp.pad(d, (0, mfm - d.shape[0]),
+                                                constant_values=PAD)
+            for d in dsts]
+    return _fused_tables_jit(tuple(begins), tuple(ends), tuple(dsts),
+                             slots=slots)
 
 
 # ---------------------------------------------------------------------------

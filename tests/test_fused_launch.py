@@ -85,6 +85,60 @@ def test_fused_issues_one_dispatch_per_round_not_per_query(monkeypatch):
     assert fused_dispatches < solo_chunks
 
 
+def test_fused_mixed_k_parity_with_solo_device(monkeypatch):
+    """k = 3 and k = 4 members, one that never launches (no fan-out),
+    finishing in different rounds of one run: every member keeps its
+    slot of the once-built tables (six members, eight slots; the k = 3
+    members' offset rows padded to k1max = 5), and each result equals
+    its solo device run."""
+    from repro import trace
+    monkeypatch.setenv("REPRO_DEVICE_DEQUE", "off")
+    g = erdos_renyi(40, 5.0, seed=17)
+    qs = [(0, 39, 4), (1, 38, 3), (2, 37, 3), (3, 36, 4), (5, 34, 3),
+          (6, 33, 4)]
+    idxs = _indexes(g, qs)
+    assert [ix.k for ix in idxs] == [k for *_, k in qs]
+    before = trace.snapshot()
+    fused = enumerate_fused_device(idxs, chunk_size=CHUNK)
+    d = trace.delta(trace.snapshot(), before)["counters"]
+    solos = [enumerate_paths_idx(idx, backend="device", chunk_size=CHUNK)
+             for idx in idxs]
+    for idx, fr, solo in zip(idxs, fused, solos):
+        _assert_equal(fr, solo, f"s={idx.s} t={idx.t} k={idx.k}")
+    chunks = [r.stats.chunks for r in solos]
+    assert len(set(chunks)) >= 3 and min(chunks) == 1
+    assert d["pathenum.enum.table_builds"] == 1
+    assert d["pathenum.enum.rounds"] == max(chunks)
+
+
+def test_fused_tables_stack_members_at_their_slots():
+    """`ops.fused_tables`: member i at slot i, offset rows transposed to
+    (k1max, n) with zero rows past a member's own k+1, slabs PAD-padded
+    to the largest, and zero/PAD slots past the last member."""
+    g = erdos_renyi(40, 5.0, seed=17)
+    idxs = _indexes(g, [(0, 39, 4), (1, 38, 3), (2, 37, 3)])
+    devs = [ix.device_arrays() for ix in idxs]
+    begin, end_all, dst = kops.fused_tables(
+        [d.begin for d in devs], [d.end for d in devs],
+        [d.dst for d in devs], slots=4)
+    n, mfm = g.n, max(d.dst.shape[0] for d in devs)
+    assert begin.shape == (4 * n,) and end_all.shape == (4, 5, n)
+    assert dst.shape == (4 * mfm,)
+    begin, end_all, dst = (np.asarray(a) for a in (begin, end_all, dst))
+    for slot, (ix, dv) in enumerate(zip(idxs, devs)):
+        np.testing.assert_array_equal(begin[slot * n:(slot + 1) * n],
+                                      ix.fwd_begin)
+        np.testing.assert_array_equal(end_all[slot, :ix.k + 1].T,
+                                      ix.fwd_end)
+        assert not end_all[slot, ix.k + 1:].any()
+        slab = dst[slot * mfm:(slot + 1) * mfm]
+        mf = ix.fwd_dst.shape[0]
+        np.testing.assert_array_equal(slab[:mf], ix.fwd_dst)
+        assert (slab[mf:] == -1).all()
+    assert not begin[3 * n:].any() and not end_all[3].any()
+    assert (dst[3 * mfm:] == -1).all()
+
+
 def test_fused_count_only_and_first_n(monkeypatch):
     monkeypatch.setenv("REPRO_DEVICE_DEQUE", "off")
     g, qs = _graph_and_queries()

@@ -82,7 +82,8 @@ def test_frontier_expand_compiles(sds, rows, max_deg):
 def test_frontier_fused_compiles(sds, rows, max_deg):
     compiled = ops._frontier_fused_jit.lower(
         sds((rows, K1)), sds((rows,)), sds((M,)), sds((M,)),
-        sds((M * N,)), sds((M * N,)), sds((M * MF,)), sds((M,), jnp.bool_),
+        sds((M * N,)), sds((M, K1, N)), sds((M,)), sds((M * MF,)),
+        sds((M,), jnp.bool_),
         max_deg=max_deg, interpret=False, use_ref=False).compile()
     _check(compiled)
 

@@ -47,7 +47,8 @@ def _spans(d, name):
 @pytest.fixture(scope="module")
 def fused_batch(tmp_path_factory):
     """One fused device batch, traced, with every fused launch's inputs
-    and outputs kept: (BatchOutput, tally delta, launches, trace dir)."""
+    and outputs and every table build kept: (BatchOutput, tally delta,
+    launches, trace dir, tables)."""
     g, qs = _graph_and_queries()
     engine = BatchPathEnum(backend="device", sharing="off",
                            chunk_size=CHUNK)
@@ -59,23 +60,31 @@ def fused_batch(tmp_path_factory):
         launches.append((paths.shape[0], max_deg, out))
         return out
 
+    tables = []
+    real_tables = kops.fused_tables
+
+    def watched_tables(*args, **kw):
+        tables.append(real_tables(*args, **kw))
+        return tables[-1]
+
     trace_dir = tmp_path_factory.mktemp("trace")
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     opts.host_tracer_level = 1
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(kops, "frontier_expand_fused", watched)
+        mp.setattr(kops, "fused_tables", watched_tables)
         before = trace.snapshot()
         jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
         out = engine.run(g, qs, count_only=False)
         jax.profiler.stop_trace()
         after = trace.snapshot()
     assert out.fused_queries == len(qs) and out.fused_dispatches >= 2
-    return out, trace.delta(after, before), launches, trace_dir
+    return out, trace.delta(after, before), launches, trace_dir, tables
 
 
 def test_dispatch_and_sync_spans_count_the_fused_launches(fused_batch):
-    out, d, launches, _ = fused_batch
+    out, d, launches, *_ = fused_batch
     assert len(launches) == out.fused_dispatches
     assert _spans(d, "pathenum.enum.dispatch")[1] == out.fused_dispatches
     assert _spans(d, "pathenum.enum.sync")[1] == out.fused_dispatches
@@ -87,13 +96,39 @@ def test_dispatch_and_sync_spans_count_the_fused_launches(fused_batch):
 def test_rounds_counter_equals_the_rounds_run(fused_batch):
     # every round pops one chunk from each live member, so the member
     # that finishes last popped one chunk in every round
-    out, d, _, _ = fused_batch
+    out, d, *_ = fused_batch
     rounds = max(it.result.stats.chunks for it in out.items)
     assert d["counters"]["pathenum.enum.rounds"] == rounds
 
 
+def test_tables_are_built_once_per_fused_run(fused_batch):
+    # one fused run: one build and one tables span, however many rounds
+    # it serves
+    _, d, *_, tables = fused_batch
+    assert d["counters"]["pathenum.enum.rounds"] >= 2
+    assert d["counters"]["pathenum.enum.table_builds"] == 1
+    assert _spans(d, "pathenum.enum.tables")[1] == 1
+    assert len(tables) == 1
+
+
+def test_table_bytes_are_the_stacked_arrays(fused_batch):
+    from repro.core import build_index
+    _, d, *_, tables = fused_batch
+    ((begin, end_all, dst),) = tables
+    g, qs = _graph_and_queries()
+    slots, k1max = _pow2(len(qs)), max(k for *_, k in qs) + 1
+    mfm = max(build_index(g, s, t, k).device_arrays().dst.shape[0]
+              for s, t, k in qs)
+    assert begin.shape == (slots * g.n,)
+    assert end_all.shape == (slots, k1max, g.n)
+    assert dst.shape == (slots * mfm,)
+    assert d["counters"]["pathenum.enum.table_bytes"] \
+        == begin.nbytes + end_all.nbytes + dst.nbytes \
+        == 4 * slots * (g.n * (k1max + 1) + mfm)
+
+
 def test_slots_counter_is_the_launches_padded_rectangles(fused_batch):
-    out, d, launches, _ = fused_batch
+    out, d, launches, *_ = fused_batch
     slots = sum(_pow2(max(rows, 8)) * _pow2(max_deg)
                 for rows, max_deg, _ in launches)
     assert d["counters"]["pathenum.enum.slots"] == slots
@@ -101,7 +136,7 @@ def test_slots_counter_is_the_launches_padded_rectangles(fused_batch):
 
 
 def test_d2h_bytes_are_the_copied_outputs_and_their_live_rows(fused_batch):
-    _, d, launches, _ = fused_batch
+    _, d, launches, *_ = fused_batch
     copied = live = 0
     for _, _, (emit, cont, ne, nc, ctr) in launches:
         copied += sum(a.nbytes for a in (emit, cont, ne, nc, ctr))
@@ -115,7 +150,7 @@ def test_d2h_bytes_are_the_copied_outputs_and_their_live_rows(fused_batch):
 
 
 def test_batch_timing_is_read_from_the_spans(fused_batch):
-    out, d, _, _ = fused_batch
+    out, d, *_ = fused_batch
     tm = out.timing
     assert tm.distance_seconds == pytest.approx(
         _spans(d, "pathenum.index.bfs")[0])
@@ -131,7 +166,7 @@ def test_batch_timing_is_read_from_the_spans(fused_batch):
 
 
 def test_round_phases_lie_inside_the_fused_span(fused_batch):
-    _, d, _, _ = fused_batch
+    _, d, *_ = fused_batch
     phases = [_spans(d, f"pathenum.enum.{p}") for p in PHASES]
     assert all(calls > 0 for _, calls in phases)
     assert sum(s for s, _ in phases) <= _spans(d, "pathenum.enum.fused")[0]
@@ -140,7 +175,7 @@ def test_round_phases_lie_inside_the_fused_span(fused_batch):
 def test_profiler_trace_holds_the_program_spans(fused_batch):
     sys.path.insert(0, str(REPO / "benchmarks"))
     from hcpe import devtrace
-    *_, trace_dir = fused_batch
+    *_, trace_dir, _ = fused_batch
     (path,) = pathlib.Path(trace_dir).glob("**/*.xplane.pb")
     names = {name for name, _, _ in devtrace.events(str(path))["host"]}
     want = {f"pathenum.enum.{p}" for p in PHASES} | {
@@ -240,8 +275,8 @@ def test_prometheus_export_carries_spans_and_counters(fused_batch):
     assert f'pathenum_span_calls_total{{span="pathenum.enum.sync"}} ' \
         f"{calls}" in lines
     for name in ("enum.slots", "enum.rounds", "enum.table_bytes",
-                 "xfer.h2d_bytes", "xfer.d2h_bytes", "xfer.d2h_live_bytes",
-                 "driver.fused"):
+                 "enum.table_builds", "xfer.h2d_bytes", "xfer.d2h_bytes",
+                 "xfer.d2h_live_bytes", "driver.fused"):
         family = "pathenum_" + name.replace(".", "_") + "_total"
         assert f"# TYPE {family} counter" in lines
         assert f"{family} {snap.program['counters']['pathenum.' + name]}" \
