@@ -36,10 +36,7 @@ def run(n: int = 200_000, avg_deg: int = 50, k: int = 5,
     for qi in range(nq):
         s = int(rng.integers(0, n))
         # pick a target within 3 hops so the query has results (§7.1 rule)
-        ds = np.asarray(bfs_mod.bfs_edge_relax(
-            __import__("jax.numpy", fromlist=["x"]).asarray(g.esrc),
-            __import__("jax.numpy", fromlist=["x"]).asarray(g.edst),
-            g.n, 3, s, -1))
+        ds, _ = bfs_mod.index_distances(g, s, -1, 3)
         cand = np.nonzero((ds >= 2) & (ds <= 3))[0]
         if cand.size == 0:
             continue

@@ -19,9 +19,14 @@ those sharing levers to the PathEnum pipeline:
      key to fresh entries, so a pre-mutation index can never serve them.
   3. **stacked BFS** — the two bounded-BFS distance passes of every
      cache-missing query are stacked into one (Q, n) frontier matrix and
-     relaxed together: one ``minimum.reduceat`` over the CSR per hop
-     serves all Q queries (the batched analogue of bfs.bfs_edge_relax,
-     and the host mirror of the mesh-vmapped BFS in distributed/engine.py).
+     relaxed together.  Where the engine's backend resolves to the
+     device, one jitted program runs them over the graph's device copy,
+     and a second lists the edges each index keeps
+     (bfs.stacked_index_inputs); on the host one
+     ``minimum.reduceat`` over the CSR per hop serves all Q queries
+     (``batched_bounded_bfs``, the numpy mirror of the device program
+     and of the mesh-vmapped BFS in distributed/engine.py).  Both give
+     the same distances, so the indexes are byte-identical.
 
 The planner still runs once per *distinct* query — plans are per-query
 decisions (§6) and do not share — and enumeration reuses the per-query
@@ -39,9 +44,11 @@ from typing import Dict, List, Optional, Sequence, Tuple, Union
 import numpy as np
 
 from .. import trace
+from . import bfs
 from . import planner as planner_mod
 from . import sharing as sharing_mod
-from .enumerate import EnumResult, EnumStats, enumerate_paths_idx
+from .enumerate import (EnumResult, EnumStats, enumerate_paths_idx,
+                        resolve_backend)
 from .graph import Graph, from_edges
 from .index import LightweightIndex, build_index
 from .join import enumerate_paths_join
@@ -323,12 +330,9 @@ def batched_index_distances(graph: Graph, queries: Sequence[Tuple[int, int, int]
     index build is byte-identical to the sequential path.  ``block`` bounds
     the (block, m) gather working set.
     """
-    out: List[Tuple[np.ndarray, np.ndarray]] = []
-    for lo in range(0, len(queries), max(block, 1)):
-        chunk = queries[lo:lo + max(block, 1)]
-        ss = np.array([q[0] for q in chunk], np.int64)
-        tt = np.array([q[1] for q in chunk], np.int64)
-        kk = np.array([q[2] for q in chunk], np.int64)
+    def stack(chunk: Sequence[Tuple[int, int, int]]
+              ) -> Tuple[np.ndarray, np.ndarray]:
+        ss, tt, kk = (np.array(col, np.int64) for col in zip(*chunk))
         kmax = int(kk.max())
         # forward: predecessors of v are the reverse-CSR neighbors
         ds = batched_bounded_bfs(graph.rindptr, graph.rindices, graph.n,
@@ -336,12 +340,10 @@ def batched_index_distances(graph: Graph, queries: Sequence[Tuple[int, int, int]
         # reverse: predecessors (in the reverse graph) are forward neighbors
         dt = batched_bounded_bfs(graph.indptr, graph.indices, graph.n,
                                  tt, ss, kmax)
-        for row, k in enumerate(kk):
-            k = int(k)
-            d_s = np.minimum(ds[row], k + 1).astype(np.int32)
-            d_t = np.minimum(dt[row], k + 1).astype(np.int32)
-            out.append((d_s, d_t))
-    return out
+        return (np.minimum(ds, kk[:, None] + 1),
+                np.minimum(dt, kk[:, None] + 1))
+
+    return bfs.distances_by_block(queries, block, stack)
 
 
 # ---------------------------------------------------------------------------
@@ -491,8 +493,13 @@ class BatchPathEnum:
         """Resolve each distinct key to (index, was_cached).
 
         Cache misses on the unmasked graph batch their BFS passes through
-        the stacked relaxation; masked queries fall back to the per-query
-        build (the mask changes the graph under the BFS).
+        the stacked relaxation, on the device where the engine's backend
+        resolves there (``resolve_backend`` before any index exists) and
+        the hop budgets fit its encoding (``bfs.fits_device``); masked
+        queries fall back to the host and the per-query build (the
+        mask changes the graph under the BFS).  A miss whose kept edges
+        the device listed builds alone from them, with no pass over the
+        graph's edges.
 
         With ``group_builds`` (sharing enabled, DESIGN.md §13) two more
         construction levers engage, both byte-identical to the solo
@@ -525,14 +532,19 @@ class BatchPathEnum:
             return resolved
 
         dists: Dict[QueryKey, Tuple[np.ndarray, np.ndarray]] = {}
+        kept: Dict[QueryKey, Optional[np.ndarray]] = {}
         if precomputed:
             dists.update({k: precomputed[k] for k in missing
                           if k in precomputed})
         unmasked = [k for k in missing if k[4] == 0 and k not in dists]
         if unmasked:
+            on_device = (resolve_backend(None, self.engine.backend)
+                         == "device" and bfs.fits_device(
+                             graph.n, max(key[3] for key in unmasked)))
             with trace.span("pathenum.index.bfs") as sp:
                 dists.update(self._stacked_dists(graph, unmasked,
-                                                 group_builds))
+                                                 group_builds, on_device,
+                                                 kept))
             timing.distance_seconds += sp.seconds
 
         build_graph = graph
@@ -550,13 +562,15 @@ class BatchPathEnum:
                 masked_missing = [kk for kk in missing if kk not in dists]
                 if masked_missing:
                     dists.update(self._stacked_dists(
-                        build_graph, masked_missing, group_builds))
+                        build_graph, masked_missing, group_builds, False,
+                        kept))
             timing.distance_seconds += sp.seconds
 
         with trace.span("pathenum.index.build") as sp:
             built: Dict[QueryKey, LightweightIndex] = {}
             if group_builds:
-                groupable = [kk for kk in missing if kk in dists]
+                groupable = [kk for kk in missing
+                             if kk in dists and kept.get(kk) is None]
                 for grp in sharing_mod.detect_groups(groupable):
                     idxs = sharing_mod.build_member_indexes(
                         build_graph,
@@ -577,7 +591,8 @@ class BatchPathEnum:
                     d_s, d_t = dists[key]
                     idx = build_index(build_graph, s, t, k,
                                       dist_fn=lambda *_a, _d=(d_s, d_t): _d,
-                                      edge_mask=eff_mask)
+                                      edge_mask=eff_mask,
+                                      kept=kept.get(key))
                 else:  # masked query — BFS must run on the filtered graph
                     idx = build_index(build_graph, s, t, k,
                                       edge_mask=eff_mask)
@@ -587,9 +602,14 @@ class BatchPathEnum:
         return resolved
 
     def _stacked_dists(self, graph: Graph, keys: List[QueryKey],
-                       dedup_pairs: bool
+                       dedup_pairs: bool, on_device: bool,
+                       kept: Dict[QueryKey, Optional[np.ndarray]]
                        ) -> Dict[QueryKey, Tuple[np.ndarray, np.ndarray]]:
-        """Stacked BFS for a list of distinct keys.
+        """Stacked BFS for a list of distinct keys, on the device
+        (``bfs.stacked_index_inputs``) or the host
+        (``batched_index_distances``).  On the device each row also
+        lists the edges its index keeps, which go into ``kept`` for the
+        keys whose budget is the row's.
 
         With ``dedup_pairs`` (sharing enabled, DESIGN.md §13) the BFS runs
         one row per distinct ``(s, t)`` *pair* at the pair's max hop
@@ -602,23 +622,32 @@ class BatchPathEnum:
         queried under many hop budgets pays for one BFS pair, not one
         per budget.
         """
+        dist_fn = (bfs.stacked_index_inputs if on_device
+                   else batched_index_distances)
         if not dedup_pairs:
-            stacked = batched_index_distances(
+            stacked = dist_fn(
                 graph, [(s, t, k) for (_, s, t, k, _, _) in keys],
                 block=self.bfs_block)
-            return dict(zip(keys, stacked))
+            if on_device:
+                kept.update((key, row[2]) for key, row in zip(keys, stacked))
+            return {key: row[:2] for key, row in zip(keys, stacked)}
         pair_k: Dict[Tuple[int, int], int] = {}
         for (_, s, t, k, _mh, _gv) in keys:
             pair_k[(s, t)] = max(pair_k.get((s, t), 0), k)
         pairs = list(pair_k)
-        stacked = batched_index_distances(
+        stacked = dist_fn(
             graph, [(s, t, pair_k[(s, t)]) for (s, t) in pairs],
             block=self.bfs_block)
         by_pair = dict(zip(pairs, stacked))
         out: Dict[QueryKey, Tuple[np.ndarray, np.ndarray]] = {}
         for key in keys:
             _, s, t, k, _mh, _gv = key
-            d_s, d_t = by_pair[(s, t)]
+            d_s, d_t, *ids = by_pair[(s, t)]
+            if k == pair_k[(s, t)]:      # the row's own budget: as it is
+                out[key] = (d_s, d_t)
+                if ids:
+                    kept[key] = ids[0]
+                continue
             out[key] = (np.minimum(d_s, k + 1).astype(np.int32),
                         np.minimum(d_t, k + 1).astype(np.int32))
         return out

@@ -88,7 +88,7 @@ class _DriverRuns(collections.abc.Mapping):  # type: ignore[type-arg]
 DRIVER_RUNS = _DriverRuns()
 
 
-def resolve_backend(idx: LightweightIndex, backend: Optional[str],
+def resolve_backend(idx: Optional[LightweightIndex], backend: Optional[str],
                     constraint=None, order: Optional[str] = None) -> str:
     """Resolve a requested backend to the one that will run (DESIGN.md §9
     fallback matrix).  Constraints are host-only state machines, so any
@@ -101,7 +101,8 @@ def resolve_backend(idx: LightweightIndex, backend: Optional[str],
     switch (same spelling as ``REPRO_SHARING`` / ``REPRO_PALLAS``): every
     query runs on the host, including explicit ``backend="device"``
     requests — the operator escape hatch when a device path misbehaves
-    in production."""
+    in production.  With ``idx`` None (the stacked index BFS, which runs
+    before any index exists) the index's rules are left out."""
     if backend is not None and backend not in ("host", "device", "auto"):
         raise ValueError(f"unknown backend {backend!r}")
     if os.environ.get("REPRO_DEVICE_ENUM", "").lower() in ("off", "0"):
@@ -115,9 +116,8 @@ def resolve_backend(idx: LightweightIndex, backend: Optional[str],
     if backend == "device":
         return "device"
     # backend == "auto"
-    if idx.k > DEVICE_AUTO_MAX_K:
-        return "host"
-    if idx.num_index_edges < DEVICE_AUTO_MIN_EDGES:
+    if idx is not None and (idx.k > DEVICE_AUTO_MAX_K or
+                            idx.num_index_edges < DEVICE_AUTO_MIN_EDGES):
         return "host"
     if os.environ.get("REPRO_DEVICE_ENUM") == "force":
         return "device"

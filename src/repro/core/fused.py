@@ -67,10 +67,6 @@ class _MemberState:
                                 canonical=canonical)
 
 
-def _next_pow2(x: int) -> int:
-    return 1 << max(x - 1, 0).bit_length() if x > 1 else 1
-
-
 def enumerate_fused_device(
     indexes: List[LightweightIndex],
     chunk_size: int = 16384,
@@ -101,7 +97,7 @@ def enumerate_fused_device(
     k1max = max(ix.k for ix in indexes) + 1
     # member i keeps slot i for the whole run: the tables are built
     # once, and a finished member's slot stays, with no row pointing at it
-    slots = _next_pow2(len(states))
+    slots = kops._next_pow2(len(states))
     with trace.span("pathenum.enum.tables"):
         begin_all, end_all, dst_all = kops.fused_tables(
             [st.dev.begin for st in states], [st.dev.end for st in states],
@@ -134,8 +130,7 @@ def enumerate_fused_device(
                 k = st.idx.k
                 last = paths[:, depth].astype(np.int64)
                 b = k - depth - 1
-                cnt = (st.idx.fwd_end[last, b] - st.idx.fwd_begin[last]) \
-                    if b >= 0 else np.zeros(paths.shape[0], np.int64)
+                cnt = st.idx.it_count(last, b)
                 if int(cnt.sum()) == 0:
                     st.stats.invalid_partials += paths.shape[0]
                     if not st.work:

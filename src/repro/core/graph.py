@@ -2,10 +2,11 @@
 
 The engine's canonical representation is a static CSR pair (forward and
 reverse) plus flat edge lists.  Vertices are int32 ids in [0, n).  All arrays
-are host numpy; ``DeviceGraph`` mirrors them as jnp arrays for the jitted /
-distributed paths.  Distances are bounded by the hop constraint ``k`` so the
-sentinel ``INF_DIST`` is any value > k; we use 0x3FFF_FFFF to stay addition-
-safe in int32.
+are host numpy; ``DeviceGraph`` mirrors both CSRs as int32 device arrays
+for the stacked BFS (core/bfs.py), uploaded once per graph object and
+cached on it (``Graph.device_arrays``).  Distances are bounded by the hop
+constraint ``k`` so the sentinel ``INF_DIST`` is any value > k; we use
+0x3FFF_FFFF to stay addition-safe in int32.
 
 Graphs are immutable values, but deployments stream (DESIGN.md §12): a
 fraud graph ingests live transactions between queries.  Mutation is
@@ -19,12 +20,38 @@ query against version v+1 — the streaming invalidation contract.
 from __future__ import annotations
 
 import dataclasses
+import threading
 from typing import Optional
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+
+from .. import trace
 
 INF_DIST = np.int32(0x3FFFFFFF)
 PAD = np.int32(-1)
+
+# one upload per graph object, even when server threads ask together
+_UPLOAD_LOCK = threading.Lock()
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class DeviceGraph:
+    """Both CSRs of a ``Graph`` as int32 device arrays (DESIGN.md §4):
+    ``indptr`` (n+1,) with ``src`` and ``dst`` (m,), the forward CSR in
+    edge order, and ``rindptr`` (n+1,) with ``rsrc`` and ``rdst`` (m,),
+    the same edges in reverse-CSR order (grouped by destination).  Each
+    CSR segment lists a vertex's predecessors in one direction: ``rsrc``
+    over ``rdst``'s segments for distances from a source, ``dst`` over
+    ``src``'s segments for distances to a target."""
+    indptr: jnp.ndarray
+    src: jnp.ndarray
+    dst: jnp.ndarray
+    rindptr: jnp.ndarray
+    rsrc: jnp.ndarray
+    rdst: jnp.ndarray
 
 
 @dataclasses.dataclass(frozen=True)
@@ -75,6 +102,31 @@ class Graph:
 
     def redst(self) -> np.ndarray:
         return self.rindices
+
+    def device_arrays(self) -> DeviceGraph:
+        """The graph's device copy, uploaded on first use and cached on
+        this object.  A graph is immutable and every mutation makes a new
+        object (``with_edges``), so the copy never goes stale and each
+        graph version is uploaded once: the ``pathenum.index.graph_upload``
+        span, its bytes counted in ``pathenum.xfer.h2d_bytes``."""
+        cached = self.__dict__.get("_device_arrays")
+        if cached is not None:
+            return cached
+        with _UPLOAD_LOCK:
+            cached = self.__dict__.get("_device_arrays")
+            if cached is None:
+                with trace.span("pathenum.index.graph_upload"):
+                    owners = np.repeat(np.arange(self.n, dtype=np.int32),
+                                       np.diff(self.rindptr))
+                    host = [np.asarray(a, np.int32) for a in (
+                        self.indptr, self.esrc, self.edst, self.rindptr,
+                        self.rindices, owners)]
+                    cached = DeviceGraph(*jax.block_until_ready(
+                        [jnp.asarray(a) for a in host]))
+                trace.count("pathenum.xfer.h2d_bytes",
+                            sum(int(a.nbytes) for a in host))
+                self.__dict__["_device_arrays"] = cached
+        return cached
 
     # -- streaming mutation (DESIGN.md §12) ---------------------------------
 

@@ -53,7 +53,8 @@ from . import clock
 from .enumerate import EnumResult, EnumStats, EngineLimit, _finalize, \
     _trim_to_first_n
 from .graph import Graph, PAD
-from .index import LightweightIndex, _offsets_from_sorted
+from .index import (LightweightIndex, _count_within, _offsets_from_sorted,
+                    _row_keys)
 from .join import enumerate_paths_join
 
 #: Union-walk node budget: a shared prefix tree larger than this falls
@@ -154,30 +155,27 @@ def _member_index_from_selection(n: int, k: int, s: int, t: int,
     order_f = np.lexsort((orig_sel, dist_t[v_sel], u_sel))
     fu_s, fv_s = u_sel[order_f], v_sel[order_f]
     fwd_eid = orig_sel[order_f]
-    fwd_begin, fwd_end = _offsets_from_sorted(fu_s, dist_t[fv_s], n, k)
 
     order_r = np.lexsort((orig_sel, dist_s[u_sel], v_sel))
     ru_s, rv_s = u_sel[order_r], v_sel[order_r]
-    rev_begin, rev_end = _offsets_from_sorted(rv_s, dist_s[ru_s], n, k)
 
     ii = np.arange(k + 1)
     lvl = (dist_s[None, :] <= ii[:, None]) \
         & (dist_t[None, :] <= (k - ii)[:, None])
     level_count = lvl.sum(axis=1).astype(np.int64)
+    key = _row_keys(fu_s, dist_t[fv_s], k)
     gamma = np.zeros(k, dtype=np.float64)
     for j in range(k):
         cj = np.nonzero(lvl[j])[0]
         if cj.size:
-            b = k - j - 1
-            cnts = fwd_end[cj, b] - fwd_begin[cj]
-            gamma[j] = float(cnts.mean())
+            gamma[j] = float(_count_within(key, cj, k - j - 1, k).mean())
 
     return LightweightIndex(
         n=n, k=k, s=s, t=t, dist_s=dist_s, dist_t=dist_t,
-        fwd_dst=fv_s.astype(np.int32), fwd_eid=fwd_eid.astype(np.int64),
-        fwd_begin=fwd_begin, fwd_end=fwd_end,
-        rev_src=ru_s.astype(np.int32), rev_begin=rev_begin, rev_end=rev_end,
-        level_count=level_count, gamma=gamma)
+        fwd_src=fu_s.astype(np.int32), fwd_dst=fv_s.astype(np.int32),
+        fwd_eid=fwd_eid.astype(np.int64), fwd_begin=None, fwd_end=None,
+        rev_src=ru_s.astype(np.int32), rev_dst=rv_s.astype(np.int32),
+        rev_begin=None, rev_end=None, level_count=level_count, gamma=gamma)
 
 
 def build_member_indexes(

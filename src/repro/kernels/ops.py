@@ -111,7 +111,7 @@ def bfs_dense(adj: jnp.ndarray, src: int | jnp.ndarray, k: int, *,
               inf: float = 1e9, block: int = BLOCK) -> jnp.ndarray:
     """Bounded BFS over a dense adjacency via k min-plus relaxations.
 
-    This is the Pallas-kernel twin of core.bfs.bfs_edge_relax for the
+    This is the Pallas-kernel twin of core.bfs's stacked relaxation for the
     dense-tile regime (small/medium graphs, or per-partition tiles of the
     distributed engine).
     """

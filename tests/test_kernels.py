@@ -40,14 +40,13 @@ def test_counting_sweep(n, q, dtype):
 
 def test_bfs_dense_matches_edge_relax():
     from repro.core import erdos_renyi
-    from repro.core.bfs import bfs_edge_relax
+    from repro.core.bfs import index_distances
     g = erdos_renyi(150, 3.0, seed=2)
     A = np.full((g.n, g.n), INF, np.float32)
     A[g.esrc, g.edst] = 1.0
     for k in (2, 5):
         dd = np.asarray(ops.bfs_dense(jnp.array(A), 0, k, inf=INF))
-        de = np.asarray(bfs_edge_relax(jnp.array(g.esrc), jnp.array(g.edst),
-                                       g.n, k, jnp.int32(0), jnp.int32(-1)))
+        de, _ = index_distances(g, 0, -1, k)
         same = np.minimum(dd, k + 1) == np.minimum(de, k + 1)
         assert np.all(same | ((dd >= k + 1) & (de >= k + 1)))
 

@@ -113,3 +113,47 @@ def test_counting_spmm_compiles(sds):
         sds((n, n), jnp.float32), sds((n, q), jnp.float32),
         interpret=False).compile()
     _check(compiled)
+
+
+def test_stacked_bfs_compiles_at_web_google_size(sds):
+    # the gg_pl deployment: n 875,713, m 5,113,754, a burst of 8 misses
+    # at k = 4; no Pallas kernel, so only the memory is checked
+    from repro.core.bfs import _stacked_bfs_jit
+    from repro.core.graph import DeviceGraph
+    n, m, rows = 875_713, 5_113_754, 8
+    graph = DeviceGraph(sds((n + 1,)), sds((m,)), sds((m,)), sds((n + 1,)),
+                        sds((m,)), sds((m,)))
+    compiled = _stacked_bfs_jit.lower(
+        graph, sds((rows,)), sds((rows,)), sds((rows,)), kmax=4).compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes >= 2 * rows * n   # int8, tiled
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < HBM_BYTES
+
+
+def test_kept_edges_compile_at_web_google_size(sds):
+    # the edges each of a burst's 8 indexes keeps, listed beside the BFS
+    from repro.core.bfs import KEPT_CAP, _kept_edges_jit
+    from repro.core.graph import DeviceGraph
+    n, m, rows = 875_713, 5_113_754, 8
+    graph = DeviceGraph(sds((n + 1,)), sds((m,)), sds((m,)), sds((n + 1,)),
+                        sds((m,)), sds((m,)))
+    compiled = _kept_edges_jit.lower(
+        graph, sds((2, rows, n), jnp.int8), sds((rows,)), sds((rows,)),
+        sds((rows,)), cap=KEPT_CAP).compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes >= 4 * rows * (KEPT_CAP + 1)
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < HBM_BYTES
+
+
+def test_device_offsets_compile_at_web_google_size(sds):
+    # an index's int32 begin (n,) and end (n, k+1) from its sorted edges
+    from repro.core.index import _device_offsets
+    n, k, mf_pad = 875_713, 4, 4096
+    compiled = _device_offsets.lower(sds((mf_pad,)), sds((mf_pad,)),
+                                     n=n, k=k).compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes >= 4 * n * (k + 2)
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < HBM_BYTES

@@ -4,10 +4,11 @@
 counts launches, slots, rounds and host↔device bytes.  These tests run
 one fused device batch (CPU, interpret mode) under a profiler trace with
 the fused launcher watched, and hold the tally to what the launches
-did: dispatches, rounds, padded slots, bytes copied back, the
-``BatchTiming`` fields the spans feed, and the phases' cover of the
-fused enumeration.  Then the solo device drivers, thread safety under
-``AsyncHcPEServer``, and the Prometheus export.
+did: dispatches, rounds, padded slots, bytes copied back, the misses'
+stacked BFS launch and the graph's one upload, the ``BatchTiming``
+fields the spans feed, and the phases' cover of the fused enumeration.
+Then the solo device drivers, thread safety under ``AsyncHcPEServer``,
+and the Prometheus export.
 """
 import asyncio
 import pathlib
@@ -20,6 +21,7 @@ import pytest
 
 from repro import trace
 from repro.core import erdos_renyi
+from repro.core import bfs
 from repro.core import enumerate as en
 from repro.core.batch import BatchPathEnum
 from repro.kernels import ops as kops
@@ -137,16 +139,90 @@ def test_slots_counter_is_the_launches_padded_rectangles(fused_batch):
 
 def test_d2h_bytes_are_the_copied_outputs_and_their_live_rows(fused_batch):
     _, d, launches, *_ = fused_batch
+    g, qs = _graph_and_queries()
     copied = live = 0
     for _, _, (emit, cont, ne, nc, ctr) in launches:
         copied += sum(a.nbytes for a in (emit, cont, ne, nc, ctr))
         rows = int(np.asarray(ne).sum()) + int(np.asarray(nc).sum())
         live += rows * emit.shape[1] * 4 + ne.nbytes + nc.nbytes + ctr.nbytes
-    assert d["counters"]["pathenum.xfer.d2h_bytes"] == copied
-    assert d["counters"]["pathenum.xfer.d2h_live_bytes"] == live
+    # besides the launches' outputs, the misses' stacked BFS copies back
+    # once its (2, rows, n) int8 distances, the (rows, KEPT_CAP) int32
+    # ids of the edges each index keeps and their (rows,) int32 counts,
+    # every row live here
+    bfs_bytes = _pow2(len(qs)) * (2 * g.n + 4 * bfs.KEPT_CAP + 4)
+    assert d["counters"]["pathenum.xfer.d2h_bytes"] == copied + bfs_bytes
+    assert d["counters"]["pathenum.xfer.d2h_live_bytes"] == live + bfs_bytes
     assert 0 < live < copied
     assert d["counters"]["pathenum.xfer.h2d_bytes"] > 0
     assert d["counters"]["pathenum.enum.table_bytes"] > 0
+
+
+def test_index_bfs_is_one_launch_over_one_graph_upload(fused_batch):
+    # the batch's four misses: one stacked BFS launch of four rows, over
+    # the graph's device copy, uploaded once for this graph object
+    _, d, *_ = fused_batch
+    g, qs = _graph_and_queries()
+    c = d["counters"]
+    assert c["pathenum.index.bfs_launches"] == 1
+    assert c["pathenum.index.bfs_rows"] == _pow2(len(qs))
+    assert c["pathenum.index.bfs_live_rows"] == len(qs)
+    assert _spans(d, "pathenum.index.graph_upload")[1] == 1
+    upload = 4 * (2 * (g.n + 1) + 4 * g.m)
+    assert c["pathenum.xfer.h2d_bytes"] >= upload + 3 * 4 * _pow2(len(qs))
+
+
+def test_graph_is_uploaded_once_per_version():
+    g, qs = _graph_and_queries()
+    engine = BatchPathEnum(backend="device", cache_capacity=0)
+    before = trace.snapshot()
+    engine.run(g, qs[:3], count_only=True)
+    engine.run(g, qs[3:], count_only=True)
+    g2 = g.add_edges(np.array([[0, 1]]))
+    engine.run(g2, qs[:1], count_only=True)
+    d = trace.delta(trace.snapshot(), before)
+    c = d["counters"]
+    assert _spans(d, "pathenum.index.graph_upload")[1] == 2
+    assert c["pathenum.index.bfs_launches"] == 3
+    # three misses ride in a launch of four rows
+    assert c["pathenum.index.bfs_rows"] == 4 + 1 + 1
+    assert c["pathenum.index.bfs_live_rows"] == 3 + 1 + 1
+    assert g.device_arrays() is g.device_arrays()
+    assert g2.device_arrays() is not g.device_arrays()
+
+
+def test_graph_upload_is_once_under_racing_threads():
+    g, _ = _graph_and_queries()
+    got, before = [], trace.snapshot()
+    barrier = threading.Barrier(8)
+
+    def ask():
+        barrier.wait(timeout=30)
+        got.append(g.device_arrays())
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=ask) for _ in range(8)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(th.is_alive() for th in threads)
+    d = trace.delta(trace.snapshot(), before)
+    assert len(got) == 8 and all(a is got[0] for a in got)
+    assert _spans(d, "pathenum.index.graph_upload")[1] == 1
+
+
+def test_host_backend_runs_no_device_bfs():
+    g, qs = _graph_and_queries()
+    before = trace.snapshot()
+    BatchPathEnum(backend="host").run(g, qs, count_only=True)
+    d = trace.delta(trace.snapshot(), before)
+    assert "pathenum.index.bfs_launches" not in d["counters"]
+    assert "pathenum.index.graph_upload" not in d["spans"]
+    assert _spans(d, "pathenum.index.bfs")[1] == 1
 
 
 def test_batch_timing_is_read_from_the_spans(fused_batch):
@@ -180,7 +256,8 @@ def test_profiler_trace_holds_the_program_spans(fused_batch):
     names = {name for name, _, _ in devtrace.events(str(path))["host"]}
     want = {f"pathenum.enum.{p}" for p in PHASES} | {
         "pathenum.batch", "pathenum.plan", "pathenum.enum.fused",
-        "pathenum.index.bfs", "pathenum.index.build"}
+        "pathenum.index.bfs", "pathenum.index.build",
+        "pathenum.index.graph_upload"}
     assert want <= names
 
 
@@ -276,7 +353,9 @@ def test_prometheus_export_carries_spans_and_counters(fused_batch):
         f"{calls}" in lines
     for name in ("enum.slots", "enum.rounds", "enum.table_bytes",
                  "enum.table_builds", "xfer.h2d_bytes", "xfer.d2h_bytes",
-                 "xfer.d2h_live_bytes", "driver.fused"):
+                 "xfer.d2h_live_bytes", "driver.fused",
+                 "index.bfs_launches", "index.bfs_rows",
+                 "index.bfs_live_rows"):
         family = "pathenum_" + name.replace(".", "_") + "_total"
         assert f"# TYPE {family} counter" in lines
         assert f"{family} {snap.program['counters']['pathenum.' + name]}" \
