@@ -1,13 +1,21 @@
 """Bounded BFS distances — the first stage of index construction (Alg. 3 L1).
 
-TPU adaptation: the queue BFS of the paper becomes k rounds of
-edge-parallel relaxation over the graph's device CSR
-(``graph.DeviceGraph``), for a stack of Q sources at once.  A round
-gathers each edge's predecessor distance for every row, a (Q, m) gather,
-and takes the min over each vertex's CSR segment of predecessors.  One
-jitted program (``_stacked_bfs_jit``) runs the rounds from every s of a
-micro-batch's cache misses and from every t on the reverse graph, and
-its one (2, Q, n) int8 result is copied back once
+TPU adaptation: the queue BFS of the paper becomes k rounds of pull
+relaxation over the graph's device copy (``graph.DeviceGraph``) for a
+stack of Q rows at once, on reached-sets bit-packed per vertex.  Each
+vertex holds ``ceil(Q / 32)`` 32-bit row masks, bit q set once row q has
+reached it, kept as four uint8 planes a mask: a (4 · ceil(Q / 32), n + 1)
+array whose columns are vertices.  XLA's TPU gather costs about 2.5 ns a
+column of such an array, against 7.7 ns an index of a 1-D uint32 array
+(PERF.md §6).  The predecessor lists are grouped by length rounded up to
+a power of two (``graph.PredLists``) and the vertices are numbered in
+that bucket order, so a round is one gather of the P padded list slots'
+columns and an OR down each bucket's ``(width, count)`` block: no (Q, m)
+gather and no permutation.  A row's distance at a vertex is the number
+of rounds 0..kmax before its bit is set.  One jitted program
+(``_stacked_bfs_jit``) runs the rounds from every s of a micro-batch's
+cache misses and to every t, puts the distances back in vertex order,
+and its one (2, Q, n) int8 result is copied back once
 (``stacked_index_distances``).  Rows are padded to a power of two with
 inert rows (no source), so a partial burst compiles nothing new.  The
 mesh-sharded BFS of distributed/engine.py and the numpy mirror
@@ -23,37 +31,78 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import trace
-from .graph import DeviceGraph, Graph
+from .graph import DeviceGraph, Graph, PredLists
 
 
 def fits_device(n: int, kmax: int) -> bool:
     """Whether the device BFS encodes hop budgets up to ``kmax`` on ``n``
-    vertices: int8 distances (sentinel kmax + 1 <= 127) and int32
-    segment keys (n·(kmax + 2) < 2^31).  Beyond, the host BFS serves."""
-    return kmax <= 126 and n * (kmax + 2) < 2**31
+    vertices: int8 distances (sentinel kmax + 1 <= 127) and int32 places
+    (the sentinel place n).  Beyond, the host BFS serves."""
+    return kmax <= 126 and n < 2**31 - 1
 
 
-def _relax(dist: jnp.ndarray, pred: jnp.ndarray, owner: jnp.ndarray,
-           last: jnp.ndarray, has_pred: jnp.ndarray, excluded: jnp.ndarray,
-           inf: jnp.ndarray) -> jnp.ndarray:
-    """One round: each vertex takes 1 + the least distance among its
-    predecessors ``pred``, where a row's ``excluded`` vertex relaxes
-    nothing (no transit through it) but may itself receive a distance.
+def _pack(hit: jnp.ndarray) -> jnp.ndarray:
+    """(Q, L) bools as (4 · ceil(Q / 32), L) uint8 planes: each column's
+    ceil(Q / 32) 32-bit row masks, a byte a plane, row q at bit q % 8 of
+    plane q // 8."""
+    rows, length = hit.shape
+    planes = 4 * -(-rows // 32)
+    bits = jnp.pad(hit, ((0, 8 * planes - rows), (0, 0))).reshape(
+        planes, 8, length).astype(jnp.uint8)
+    shift = jnp.arange(8, dtype=jnp.uint8)[None, :, None]
+    return jnp.sum(bits << shift, axis=1, dtype=jnp.uint8)
 
-    The min over each vertex's CSR segment is a running max along the
-    sorted edge array: ``owner * (inf + 1) + (inf - d)`` grows with the
-    segment, so its running max at the segment's ``last`` entry is the
-    segment's own base plus ``inf`` minus its least distance (int32:
-    n·(kmax + 2) < 2^31).  A scatter-min, pulled over the sorted
-    segments or pushed along the forward edges, is four to five times
-    slower on a TPU v5e at web-Google scale (PERF.md §6)."""
-    gathered = jnp.where(pred[None, :] == excluded[:, None], inf,
-                         dist[:, pred])
-    width = inf + 1
-    run = jax.lax.cummax(owner[None, :] * width + (inf - gathered), axis=1)
-    base = jnp.arange(dist.shape[1], dtype=jnp.int32)[None, :] * width
-    seg = jnp.where(has_pred, inf - (run[:, last] - base), inf)
-    return jnp.minimum(dist, jnp.minimum(seg, inf - 1) + 1)
+
+def _unset(planes: jnp.ndarray, rows: int) -> jnp.ndarray:
+    """(rows, L) int8: 1 where row q's bit is clear, the inverse of
+    ``_pack``."""
+    q = jnp.arange(rows, dtype=jnp.uint8)[:, None]
+    bit = jnp.repeat(planes, 8, axis=0)[:rows] >> (q % 8)
+    return (1 - (bit & 1)).astype(jnp.int8)
+
+
+def _pull(sent: jnp.ndarray, lists: PredLists) -> jnp.ndarray:
+    """Every place's new reach: the OR of ``sent`` over its predecessor
+    list, one gather of the padded slots' columns and an OR down each
+    bucket's block; 0 at the places without predecessors and at the
+    sentinel."""
+    planes = sent.shape[0]
+    got = sent[:, lists.pred]
+    parts, off = [], 0
+    for width, count in lists.buckets:
+        block = got[:, off:off + width * count].reshape(planes, width, count)
+        parts.append(jax.lax.reduce(block, np.uint8(0), jax.lax.bitwise_or,
+                                    (1,)))
+        off += width * count
+    rest = sent.shape[1] - sum(count for _, count in lists.buckets)
+    return jnp.concatenate(parts + [jnp.zeros((planes, rest), jnp.uint8)],
+                           axis=1)
+
+
+def _distances(lists: PredLists, roots: jnp.ndarray, excluded: jnp.ndarray,
+               kmax: int) -> jnp.ndarray:
+    """(Q, n) int8 distances from ``roots`` over ``lists``, sentinel kmax
+    + 1, where a row's ``excluded`` vertex relays nothing (no transit
+    through it) but may itself be reached; a root of -1 reaches
+    nothing."""
+    n, rows = lists.pos.shape[0], roots.shape[0]
+    place = jnp.arange(n + 1, dtype=jnp.int32)[None, :]
+
+    def at(v):
+        # a vertex's place, and for -1 one past the sentinel
+        return jnp.where(v >= 0, lists.pos[jnp.maximum(v, 0)], n + 1)[:, None]
+
+    keep = ~_pack(place == at(excluded))
+
+    def relax(_, carry):
+        reached, unset = carry
+        reached = reached | _pull(reached & keep, lists)
+        return reached, unset + _unset(reached, rows)
+
+    reached = _pack(place == at(roots))
+    _, unset = jax.lax.fori_loop(0, kmax, relax,
+                                 (reached, _unset(reached, rows)))
+    return unset[:, lists.pos]
 
 
 @functools.partial(jax.jit, static_argnames=("kmax",))
@@ -65,24 +114,10 @@ def _stacked_bfs_jit(graph: DeviceGraph, srcs: jnp.ndarray,
     ``kmax`` rounds, each row clipped to its own ``ks[q] + 1`` sentinel.
     A row whose endpoints are -1 has no source and stays at the
     sentinel."""
-    n = graph.indptr.shape[0] - 1
-    inf = jnp.int32(kmax + 1)
-    col = jnp.arange(n, dtype=jnp.int32)[None, :]
-
-    def bfs(roots, excluded, pred, owner, indptr):
-        dist = jnp.where(col == roots[:, None], 0, inf).astype(jnp.int32)
-        if pred.shape[0] == 0:
-            return dist
-        last = jnp.maximum(indptr[1:] - 1, 0)
-        has_pred = (indptr[1:] > indptr[:-1])[None, :]
-        return jax.lax.fori_loop(
-            0, kmax, lambda _, d: _relax(d, pred, owner, last, has_pred,
-                                         excluded, inf), dist)
-
-    ds = bfs(srcs, tgts, graph.rsrc, graph.rdst, graph.rindptr)
-    dt = bfs(tgts, srcs, graph.dst, graph.src, graph.indptr)
-    out = jnp.minimum(jnp.stack([ds, dt]), ks[None, :, None] + 1)
-    return out.astype(jnp.int8)
+    ds = _distances(graph.fwd, srcs, tgts, kmax)
+    dt = _distances(graph.rev, tgts, srcs, kmax)
+    return jnp.minimum(jnp.stack([ds, dt]),
+                       (ks[None, :, None] + 1).astype(jnp.int8))
 
 
 def _query_rows(queries: Sequence[Tuple[int, int, int]]
@@ -105,16 +140,20 @@ def stacked_bfs(graph: Graph, queries: Sequence[Tuple[int, int, int]]
     """The stacked BFS of ``(s, t, k)`` queries on the device: one launch
     over the graph's cached device copy, rows padded to a power of two;
     returns the (2, rows, n) int8 device result (rows past the queries
-    are inert).  Counts the launch and its padded and live rows."""
+    are inert).  Counts the launch, its padded and live rows, and the
+    padded list slots its rounds gather (kmax rounds in each direction,
+    each gathering every slot's column of mask planes once)."""
     kmax = max(k for _, _, k in queries)
     if not fits_device(graph.n, kmax):
         raise ValueError(f"k = {kmax} on n = {graph.n} overflows the device "
-                         f"BFS's int8 distances or int32 segment keys")
+                         f"BFS's int8 distances or int32 places")
     dev = graph.device_arrays()
     ss, tt, kk, kmax = _query_rows(queries)
     trace.count("pathenum.index.bfs_launches")
     trace.count("pathenum.index.bfs_rows", ss.shape[0])
     trace.count("pathenum.index.bfs_live_rows", len(queries))
+    trace.count("pathenum.index.bfs_gather_slots",
+                kmax * (dev.fwd.pred.shape[0] + dev.rev.pred.shape[0]))
     return _stacked_bfs_jit(dev, ss, tt, kk, kmax=kmax)
 
 

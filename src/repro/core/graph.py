@@ -2,9 +2,10 @@
 
 The engine's canonical representation is a static CSR pair (forward and
 reverse) plus flat edge lists.  Vertices are int32 ids in [0, n).  All arrays
-are host numpy; ``DeviceGraph`` mirrors both CSRs as int32 device arrays
-for the stacked BFS (core/bfs.py), uploaded once per graph object and
-cached on it (``Graph.device_arrays``).  Distances are bounded by the hop
+are host numpy; ``DeviceGraph`` holds the edge list and each vertex's
+predecessor lists, grouped by pow2 length, as int32 device arrays for the
+stacked BFS (core/bfs.py), uploaded once per graph object and cached on
+it (``Graph.device_arrays``).  Distances are bounded by the hop
 constraint ``k`` so the sentinel ``INF_DIST`` is any value > k; we use
 0x3FFF_FFFF to stay addition-safe in int32.
 
@@ -21,7 +22,7 @@ from __future__ import annotations
 
 import dataclasses
 import threading
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -38,20 +39,59 @@ _UPLOAD_LOCK = threading.Lock()
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
+class PredLists:
+    """Every vertex's predecessor list in one BFS direction, grouped by
+    length rounded up to a power of two (DESIGN.md §4).  ``pos`` (n,) is
+    each vertex's place in bucket order: the buckets by width, ascending,
+    then the vertices without predecessors, each group in id order; place
+    n is a sentinel that relays nothing.  ``pred`` (P,) holds bucket after
+    bucket, bucket ``(width, count)`` as a ``(width, count)`` block in
+    row-major order: entry ``[j, i]`` is the place of the j-th
+    predecessor of the bucket's i-th vertex, or n past its list.
+    ``buckets`` is static, read from the graph's own degrees."""
+    pred: jnp.ndarray
+    pos: jnp.ndarray
+    buckets: Tuple[Tuple[int, int], ...] = dataclasses.field(
+        metadata=dict(static=True))
+
+
+def pred_lists(indptr: np.ndarray, indices: np.ndarray) -> PredLists:
+    """``PredLists`` of the CSR ``(indptr, indices)``, whose segment v
+    lists v's predecessors, as host arrays: one O(n + m) pass."""
+    n = indptr.shape[0] - 1
+    deg = np.diff(indptr).astype(np.int64)
+    # log2 of each list's pow2 width: the bit length of deg - 1
+    lg = np.frexp(np.maximum(deg - 1, 0).astype(np.float64))[1]
+    key = np.where(deg > 0, lg, 64)
+    order = np.argsort(key, kind="stable")
+    pos = np.empty(n, np.int64)
+    pos[order] = np.arange(n)
+    lgs, counts = np.unique(lg[deg > 0], return_counts=True)
+    widths = np.left_shift(1, lgs).astype(np.int64)
+    starts = np.cumsum(counts) - counts
+    offs = np.cumsum(widths * counts) - widths * counts
+    owner = np.repeat(np.arange(n), deg)
+    b = np.searchsorted(lgs, lg[owner])
+    rank = np.arange(owner.shape[0]) - indptr[owner]
+    pred = np.full(int((widths * counts).sum()), n, np.int32)
+    pred[offs[b] + rank * counts[b] + pos[owner] - starts[b]] = \
+        pos[indices]
+    return PredLists(pred, pos.astype(np.int32),
+                     tuple((int(w), int(c)) for w, c in zip(widths, counts)))
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
 class DeviceGraph:
-    """Both CSRs of a ``Graph`` as int32 device arrays (DESIGN.md §4):
-    ``indptr`` (n+1,) with ``src`` and ``dst`` (m,), the forward CSR in
-    edge order, and ``rindptr`` (n+1,) with ``rsrc`` and ``rdst`` (m,),
-    the same edges in reverse-CSR order (grouped by destination).  Each
-    CSR segment lists a vertex's predecessors in one direction: ``rsrc``
-    over ``rdst``'s segments for distances from a source, ``dst`` over
-    ``src``'s segments for distances to a target."""
-    indptr: jnp.ndarray
+    """A ``Graph`` on the device (DESIGN.md §4): ``src`` and ``dst``
+    (m,), the edges in forward-CSR order, and the predecessor lists of
+    the BFS's two directions: ``fwd`` the in-neighbours, for distances
+    from a source, ``rev`` the out-neighbours, for distances to a
+    target."""
     src: jnp.ndarray
     dst: jnp.ndarray
-    rindptr: jnp.ndarray
-    rsrc: jnp.ndarray
-    rdst: jnp.ndarray
+    fwd: PredLists
+    rev: PredLists
 
 
 @dataclasses.dataclass(frozen=True)
@@ -116,15 +156,14 @@ class Graph:
             cached = self.__dict__.get("_device_arrays")
             if cached is None:
                 with trace.span("pathenum.index.graph_upload"):
-                    owners = np.repeat(np.arange(self.n, dtype=np.int32),
-                                       np.diff(self.rindptr))
-                    host = [np.asarray(a, np.int32) for a in (
-                        self.indptr, self.esrc, self.edst, self.rindptr,
-                        self.rindices, owners)]
-                    cached = DeviceGraph(*jax.block_until_ready(
-                        [jnp.asarray(a) for a in host]))
-                trace.count("pathenum.xfer.h2d_bytes",
-                            sum(int(a.nbytes) for a in host))
+                    host = DeviceGraph(
+                        np.asarray(self.esrc, np.int32),
+                        np.asarray(self.edst, np.int32),
+                        pred_lists(self.rindptr, self.rindices),
+                        pred_lists(self.indptr, self.indices))
+                    cached = jax.block_until_ready(jax.device_put(host))
+                trace.count("pathenum.xfer.h2d_bytes", sum(
+                    a.nbytes for a in jax.tree_util.tree_leaves(host)))
                 self.__dict__["_device_arrays"] = cached
         return cached
 

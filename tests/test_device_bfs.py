@@ -5,11 +5,13 @@ misses over the graph's device copy.  These tests hold it to the host's
 stacked relaxation (``batch.batched_bounded_bfs``) and to the queue BFS
 of the oracle, row for row: excluded endpoints, rows whose k is below
 the launch's, padded rows, vertices without predecessors, isolated
-vertices and an empty edge set.  The edges each index keeps, listed on
-the device beside the distances, are the host pass's.  Then the served
-path: with ``backend="device"`` the batch engine's indexes are
-byte-identical to ``build_index``'s, its path sets are the oracle's, and
-it fills no dense host offset table.
+vertices and an empty edge set, and what the bit-packed rounds add: a
+full 32-row mask and two masks, predecessor lists in buckets of width 1
+to 64, rows that share an excluded vertex or a source.  The edges each
+index keeps, listed on the device beside the distances, are the host
+pass's.  Then the served path: with ``backend="device"`` the batch
+engine's indexes are byte-identical to ``build_index``'s, its path sets
+are the oracle's, and it fills no dense host offset table.
 """
 import dataclasses
 
@@ -117,6 +119,49 @@ def test_kept_edges_of_an_empty_edge_set():
     g = from_edges(6, np.zeros((0, 2), np.int64))
     (_, _, kept), = bfs.stacked_index_inputs(g, [(1, 2, 3)])
     assert kept.size == 0
+
+
+def _hub_graph():
+    # hub 0 hears from 1..40 (a list of width 64) and speaks to 41..59;
+    # in-degrees 1, 2, 3, 5 and 9 fill the buckets of width 1 to 16
+    rng = np.random.default_rng(5)
+    edges = [(u, 0) for u in range(1, 41)] + [(0, v) for v in range(41, 60)]
+    for v, d in zip(range(41, 60), [1, 2, 3, 5, 9] * 4):
+        edges += [(int(u), v) for u in rng.choice(range(1, 41), d,
+                                                  replace=False)]
+    edges += [(v, v - 40) for v in range(41, 60)]
+    return from_edges(60, np.array(edges))
+
+
+def _mask_case(name):
+    """(graph, queries) that reach what the bit-packed rounds add."""
+    g = SUITE["pl_hub"]
+    if name in ("32_rows", "40_rows"):
+        # 32 rows fill one 32-bit mask; 40 pad to 64 rows, two masks
+        rows = int(name.split("_")[0])
+        return g, _queries(g, rows, [2 + i % 4 for i in range(rows)])
+    if name == "hub_buckets":
+        h = _hub_graph()
+        return h, [(41, 0, 4), (0, 50, 3), (45, 59, 5), (3, 7, 4), (0, 1, 2)]
+    if name == "shared_excluded":
+        return g, [(3, 60, 4), (11, 60, 5), (25, 60, 3)]
+    if name == "shared_source":
+        return g, [(7, 2, 4), (7, 70, 5), (7, 33, 3)]
+    assert name == "no_in_edges"
+    return from_edges(5, np.zeros((0, 2), np.int64)), [(0, 4, 3), (0, 2, 3),
+                                                       (1, 4, 2)]
+
+
+@pytest.mark.parametrize("name", ["32_rows", "40_rows", "hub_buckets",
+                                  "shared_excluded", "shared_source",
+                                  "no_in_edges"])
+def test_mask_rounds_equal_host_and_oracle_row_for_row(name):
+    _check_rows(*_mask_case(name))
+
+
+def test_hub_graph_fills_the_pow2_buckets():
+    widths = [w for w, _ in _hub_graph().device_arrays().fwd.buckets]
+    assert widths == [1, 2, 4, 8, 16, 64]
 
 
 def test_excluded_endpoint_relaxes_nothing_but_is_reached():

@@ -12,6 +12,7 @@ this file loads the TPU compiler, and nothing happens at import time.
 """
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -115,18 +116,66 @@ def test_counting_spmm_compiles(sds):
     _check(compiled)
 
 
-def test_stacked_bfs_compiles_at_web_google_size(sds):
-    # the gg_pl deployment: n 875,713, m 5,113,754, a burst of 8 misses
-    # at k = 4; no Pallas kernel, so only the memory is checked
+# gg_pl's graph (benchmarks/hcpe/configs/gg_pl.json, seed 0): n, m and
+# its (width, count) predecessor buckets, the same in both directions
+# since every edge is symmetric; P = 6,764,387 padded list slots
+GG_N, GG_M = 875_713, 5_113_754
+GG_BUCKETS = ((1, 53_793), (2, 100_333), (4, 263_304), (8, 314_901),
+              (16, 103_406), (32, 19_000), (64, 4_305), (128, 1_179),
+              (256, 363), (512, 112), (1024, 38), (2048, 13), (4096, 4),
+              (8192, 2))
+
+
+def _gg_graph(sds):
+    from repro.core.graph import DeviceGraph, PredLists
+    slots = sum(w * c for w, c in GG_BUCKETS)
+    assert slots == 6_764_387
+    lists = PredLists(sds((slots,)), sds((GG_N,)), GG_BUCKETS)
+    return DeviceGraph(sds((GG_M,)), sds((GG_M,)), lists, lists)
+
+
+def test_bucket_layout_lists_every_edge_once():
+    # the upload's layout, whose shapes the compiles below take, on a
+    # small graph in both directions: widths the least power of two over
+    # each list, vertices in bucket order, each list's edges once, and
+    # padding only to the sentinel
+    from repro.core.graph import power_law, pred_lists
+    g = power_law(300, 5.0, seed=3)
+    for ptr, idx in ((g.rindptr, g.rindices), (g.indptr, g.indices)):
+        lists = pred_lists(ptr, idx)
+        deg = np.diff(ptr)
+        order = np.argsort(lists.pos)
+        assert sorted(lists.pos.tolist()) == list(range(g.n))
+        assert lists.pred.shape[0] == sum(w * c for w, c in lists.buckets)
+        place, off = 0, 0
+        for width, count in lists.buckets:
+            block = lists.pred[off:off + width * count].reshape(width, count)
+            members = order[place:place + count]
+            assert (np.diff(members) > 0).all()
+            for i, v in enumerate(members):
+                assert width // 2 < deg[v] <= width
+                got = block[:, i]
+                np.testing.assert_array_equal(
+                    np.sort(got[got != g.n]),
+                    np.sort(lists.pos[idx[ptr[v]:ptr[v + 1]]]))
+                assert (got == g.n).sum() == width - deg[v]
+            place, off = place + count, off + width * count
+        assert (deg[order[place:]] == 0).all()
+        widths = [w for w, _ in lists.buckets]
+        assert widths == sorted(set(widths))
+
+
+@pytest.mark.parametrize("rows", [8, 40])
+def test_stacked_bfs_compiles_at_web_google_size(sds, rows):
+    # the gg_pl deployment, a burst of 8 misses at k = 4 (one 32-bit
+    # mask), and 40 rows (two); no Pallas kernel, so only the memory is
+    # checked
     from repro.core.bfs import _stacked_bfs_jit
-    from repro.core.graph import DeviceGraph
-    n, m, rows = 875_713, 5_113_754, 8
-    graph = DeviceGraph(sds((n + 1,)), sds((m,)), sds((m,)), sds((n + 1,)),
-                        sds((m,)), sds((m,)))
     compiled = _stacked_bfs_jit.lower(
-        graph, sds((rows,)), sds((rows,)), sds((rows,)), kmax=4).compile()
+        _gg_graph(sds), sds((rows,)), sds((rows,)), sds((rows,)),
+        kmax=4).compile()
     mem = compiled.memory_analysis()
-    assert mem.output_size_in_bytes >= 2 * rows * n   # int8, tiled
+    assert mem.output_size_in_bytes >= 2 * rows * GG_N   # int8, tiled
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes) < HBM_BYTES
 
@@ -134,13 +183,10 @@ def test_stacked_bfs_compiles_at_web_google_size(sds):
 def test_kept_edges_compile_at_web_google_size(sds):
     # the edges each of a burst's 8 indexes keeps, listed beside the BFS
     from repro.core.bfs import KEPT_CAP, _kept_edges_jit
-    from repro.core.graph import DeviceGraph
-    n, m, rows = 875_713, 5_113_754, 8
-    graph = DeviceGraph(sds((n + 1,)), sds((m,)), sds((m,)), sds((n + 1,)),
-                        sds((m,)), sds((m,)))
+    rows = 8
     compiled = _kept_edges_jit.lower(
-        graph, sds((2, rows, n), jnp.int8), sds((rows,)), sds((rows,)),
-        sds((rows,)), cap=KEPT_CAP).compile()
+        _gg_graph(sds), sds((2, rows, GG_N), jnp.int8), sds((rows,)),
+        sds((rows,)), sds((rows,)), cap=KEPT_CAP).compile()
     mem = compiled.memory_analysis()
     assert mem.output_size_in_bytes >= 4 * rows * (KEPT_CAP + 1)
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes

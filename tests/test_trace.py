@@ -167,8 +167,16 @@ def test_index_bfs_is_one_launch_over_one_graph_upload(fused_batch):
     assert c["pathenum.index.bfs_rows"] == _pow2(len(qs))
     assert c["pathenum.index.bfs_live_rows"] == len(qs)
     assert _spans(d, "pathenum.index.graph_upload")[1] == 1
-    upload = 4 * (2 * (g.n + 1) + 4 * g.m)
+    # the edge list, and per direction the padded predecessor lists and
+    # each vertex's place in bucket order
+    dev = g.device_arrays()
+    slots = dev.fwd.pred.shape[0] + dev.rev.pred.shape[0]
+    assert min(dev.fwd.pred.shape[0], dev.rev.pred.shape[0]) >= g.m
+    upload = 4 * (2 * g.m + slots + 2 * g.n)
     assert c["pathenum.xfer.h2d_bytes"] >= upload + 3 * 4 * _pow2(len(qs))
+    # kmax rounds a direction, each gathering the direction's slots once
+    kmax = max(k for *_, k in qs)
+    assert c["pathenum.index.bfs_gather_slots"] == kmax * slots
 
 
 def test_graph_is_uploaded_once_per_version():
@@ -355,7 +363,7 @@ def test_prometheus_export_carries_spans_and_counters(fused_batch):
                  "enum.table_builds", "xfer.h2d_bytes", "xfer.d2h_bytes",
                  "xfer.d2h_live_bytes", "driver.fused",
                  "index.bfs_launches", "index.bfs_rows",
-                 "index.bfs_live_rows"):
+                 "index.bfs_live_rows", "index.bfs_gather_slots"):
         family = "pathenum_" + name.replace(".", "_") + "_total"
         assert f"# TYPE {family} counter" in lines
         assert f"{family} {snap.program['counters']['pathenum.' + name]}" \
