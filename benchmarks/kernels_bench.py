@@ -125,24 +125,4 @@ def run() -> List[Row]:
     rows.append(("kernels/counting_spmm_n1024_q128", us,
                  f"flops={flops};tpu_compute_term_us={flops/197e12*1e6:.3f}"))
 
-    B, L, H, D = 1, 1024, 8, 64
-    qq = jnp.array(rng.standard_normal((B, L, H, D)), jnp.float32)
-    kk = jnp.array(rng.standard_normal((B, L, H, D)), jnp.float32)
-    vv = jnp.array(rng.standard_normal((B, L, H, D)), jnp.float32)
-    f3 = jax.jit(lambda a, b, c: ref.mha_ref(a, b, c, causal=True))
-    us = _time(f3, qq, kk, vv)
-    flops = 4 * B * H * L * L * D
-    rows.append(("kernels/attention_L1024", us,
-                 f"flops={flops};tpu_compute_term_us={flops/197e12*1e6:.3f}"))
-
-    S = 8192
-    q1 = jnp.array(rng.standard_normal((4, H, D)), jnp.float32)
-    kc = jnp.array(rng.standard_normal((4, S, 2, D)), jnp.float32)
-    vc = jnp.array(rng.standard_normal((4, S, 2, D)), jnp.float32)
-    lens = jnp.array([S, S, S // 2, 7], jnp.int32)
-    f4 = jax.jit(ref.decode_attention_ref)
-    us = _time(f4, q1, kc, vc, lens)
-    bytes_ = 4 * S * 2 * D * 2 * 4
-    rows.append(("kernels/decode_attn_S8192", us,
-                 f"bytes={bytes_};tpu_mem_term_us={bytes_/819e9*1e6:.3f}"))
     return rows

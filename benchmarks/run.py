@@ -15,12 +15,11 @@ import time
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None)
-    ap.add_argument("--skip-collectives", action="store_true")
     args = ap.parse_args()
 
     from repro.compile_cache import use_compile_cache
     use_compile_cache()
-    from . import kernels_bench, paper_tables, roofline
+    from . import kernels_bench, paper_tables
 
     suites = []
     for fn in paper_tables.ALL:
@@ -40,10 +39,6 @@ def main() -> None:
     from . import sharing
     suites.append(("fig_sharing", sharing.run))
     suites.append(("kernels", kernels_bench.run))
-    suites.append(("roofline", roofline.run))
-    if not args.skip_collectives:
-        from . import collectives
-        suites.append(("collectives", collectives.run))
 
     print("name,us_per_call,derived")
     failures = 0

@@ -19,9 +19,8 @@ per-uid quota shown here is the client-level sibling of the per-tenant
 ``max_pending`` quota the registry flow adds.
 
 Siblings: examples/batch_serving.py (the sync HcPE batch front-end),
-examples/multi_tenant_serving.py (many tenant graphs behind one server,
-per-tenant quotas) and examples/serve_batch.py (LM decode serving,
-unrelated to HcPE).
+and examples/multi_tenant_serving.py (many tenant graphs behind one
+server, per-tenant quotas).
 """
 import asyncio
 import time

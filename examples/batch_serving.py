@@ -12,9 +12,7 @@ graph wraps into a one-tenant ``GraphRegistry`` under the default
 ``graph_id`` (DESIGN.md §8), so this demo is byte-identical to the
 pre-tenancy server.
 
-Not to be confused with its similarly-named siblings:
-  * examples/serve_batch.py — **LM decode** serving (continuous batching
-    over decode slots, serving/engine.py); no path queries involved.
+Siblings:
   * examples/async_serving.py — the **async** HcPE front-end
     (AsyncHcPEServer: admission control + deadline-aware micro-batching)
     layered over the same engine this demo drives synchronously.

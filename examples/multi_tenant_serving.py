@@ -18,9 +18,8 @@ engine.  The demo shows the tenant dimension end to end:
     ``STATUS_REJECTED_TENANT_QUOTA`` while its neighbor is unaffected.
 
 This file is the runnable mirror of the README "Multi-tenant
-quickstart".  Siblings: examples/batch_serving.py (single-graph sync),
-examples/async_serving.py (single-graph async + SLOs),
-examples/serve_batch.py (LM decode serving, unrelated to HcPE).
+quickstart".  Siblings: examples/batch_serving.py (single-graph sync)
+and examples/async_serving.py (single-graph async + SLOs).
 """
 import asyncio
 

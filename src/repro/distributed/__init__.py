@@ -1,1 +1,1 @@
-from . import sharding
+"""Mesh-sharded PathEnum: engine.py (DESIGN.md §2, last bullet)."""

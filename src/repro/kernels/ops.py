@@ -21,8 +21,6 @@ import numpy as np
 
 from .. import trace
 from . import ref
-from .decode_attention import decode_attention as _decode_pallas
-from .flash_attention import flash_attention as _flash_pallas
 from .frontier_expand import PAD, frontier_masks as _frontier_pallas
 from .frontier_expand import frontier_fused_masks as _frontier_fused_pallas
 from .semiring_spmm import BLOCK, counting_spmm as _counting_pallas
@@ -618,55 +616,3 @@ def frontier_deque_round(
                             cfg=cfg, interpret=_interpret(),
                             use_ref=not _enabled())
 
-
-# ---------------------------------------------------------------------------
-# LM attention ops
-# ---------------------------------------------------------------------------
-
-def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
-                    causal: bool = True, window: int | None = None,
-                    scale: float | None = None, bq: int = 128,
-                    bk: int = 128) -> jnp.ndarray:
-    if not _enabled():
-        return ref.mha_ref(q, k, v, causal=causal, scale=scale, window=window)
-    B, Lq, H, D = q.shape
-    _, Lk, Hkv, _ = k.shape
-    bq_eff = min(bq, max(8, Lq))
-    bk_eff = min(bk, max(8, Lk))
-    needs_pad = (Lq % bq_eff != 0) or (Lk % bk_eff != 0)
-    if needs_pad and (not causal or Lq != Lk):
-        # Padding shifts the causal diagonal when Lq != Lk; production
-        # shapes (4k/32k/500k) are tile-aligned so this fallback only
-        # serves ragged test shapes.
-        return ref.mha_ref(q, k, v, causal=causal, scale=scale, window=window)
-    if needs_pad:
-        # Lq == Lk: pad both ends equally.  Padded KV columns sit past every
-        # real row index so the causal mask removes them; padded Q rows are
-        # sliced off below.
-        q = _pad_to(q, 1, bq_eff, 0)
-        k = _pad_to(k, 1, bk_eff, 0)
-        v = _pad_to(v, 1, bk_eff, 0)
-        if q.shape[1] != k.shape[1]:
-            pad_len = max(q.shape[1], k.shape[1])
-            q = _pad_to(q, 1, pad_len, 0)
-            k = _pad_to(k, 1, pad_len, 0)
-            v = _pad_to(v, 1, pad_len, 0)
-    out = _flash_pallas(q, k, v, causal=causal, window=window,
-                        scale=scale, bq=bq_eff, bk=bk_eff,
-                        interpret=_interpret())
-    return out[:, :Lq]
-
-
-def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
-                     v_cache: jnp.ndarray, lengths: jnp.ndarray, *,
-                     scale: float | None = None,
-                     bs: int = 512) -> jnp.ndarray:
-    if not _enabled():
-        return ref.decode_attention_ref(q, k_cache, v_cache, lengths,
-                                        scale=scale)
-    B, S, Hkv, D = k_cache.shape
-    bs_eff = min(bs, max(8, S))
-    k_p = _pad_to(k_cache, 1, bs_eff, 0)
-    v_p = _pad_to(v_cache, 1, bs_eff, 0)
-    return _decode_pallas(q, k_p, v_p, lengths, scale=scale, bs=bs_eff,
-                          interpret=_interpret())

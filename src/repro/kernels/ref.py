@@ -5,7 +5,6 @@ assert allclose against these functions (tests/test_kernels.py).
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 
@@ -129,51 +128,3 @@ def frontier_fused_masks_ref(paths, rank, tvec, depthv, begin, endb, dst,
     return (jnp.where(emit | cont, vnew, pad), emit.astype(jnp.int32),
             cont.astype(jnp.int32), counters)
 
-
-# ---------------------------------------------------------------------------
-# Flash attention (LM prefill / train)
-# ---------------------------------------------------------------------------
-
-def mha_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-            causal: bool = True, scale: float | None = None,
-            window: int | None = None) -> jnp.ndarray:
-    """Reference attention.  q (B, Lq, H, D), k/v (B, Lk, Hkv, D) with GQA
-    broadcast when H != Hkv.  Optional causal mask and local window."""
-    B, Lq, H, D = q.shape
-    _, Lk, Hkv, _ = k.shape
-    if scale is None:
-        scale = 1.0 / (D ** 0.5)
-    group = H // Hkv
-    kq = jnp.repeat(k, group, axis=2) if group > 1 else k
-    vq = jnp.repeat(v, group, axis=2) if group > 1 else v
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, kq).astype(jnp.float32) * scale
-    if causal:
-        qi = jnp.arange(Lq)[:, None] + (Lk - Lq)
-        ki = jnp.arange(Lk)[None, :]
-        mask = qi >= ki
-        if window is not None:
-            mask &= (qi - ki) < window
-        logits = jnp.where(mask[None, None], logits, -jnp.inf)
-    p = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhqk,bkhd->bqhd", p.astype(vq.dtype), vq)
-    return out.astype(q.dtype)
-
-
-def decode_attention_ref(q: jnp.ndarray, k_cache: jnp.ndarray,
-                         v_cache: jnp.ndarray, lengths: jnp.ndarray,
-                         scale: float | None = None) -> jnp.ndarray:
-    """Single-token GQA decode.  q (B, H, D); caches (B, S, Hkv, D);
-    lengths (B,) valid prefix lengths."""
-    B, H, D = q.shape
-    _, S, Hkv, _ = k_cache.shape
-    if scale is None:
-        scale = 1.0 / (D ** 0.5)
-    group = H // Hkv
-    kq = jnp.repeat(k_cache, group, axis=2) if group > 1 else k_cache
-    vq = jnp.repeat(v_cache, group, axis=2) if group > 1 else v_cache
-    logits = jnp.einsum("bhd,bshd->bhs", q, kq).astype(jnp.float32) * scale
-    mask = jnp.arange(S)[None, None, :] < lengths[:, None, None]
-    logits = jnp.where(mask, logits, -jnp.inf)
-    p = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhs,bshd->bhd", p.astype(vq.dtype), vq)
-    return out.astype(q.dtype)

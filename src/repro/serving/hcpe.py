@@ -9,8 +9,7 @@ and reports batch-level serving metrics: latency percentiles, throughput,
 and cache reuse (global and per tenant).  This is the paper's "online
 scenario" (§7.1: 1000-query sets, response time = first results out)
 expressed as a service API; the README "API reference" section documents
-the public surface; the LM serving analogue with continuous batching
-lives in serving/engine.py.
+the public surface.
 """
 from __future__ import annotations
 

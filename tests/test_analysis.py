@@ -209,7 +209,6 @@ TYPED_MODULES = [
     "src/repro/core/rank.py",
     "src/repro/kernels/ops.py",
     "src/repro/serving/__init__.py",
-    "src/repro/serving/engine.py",
     "src/repro/serving/hcpe.py",
     "src/repro/serving/async_server.py",
     "src/repro/serving/registry.py",

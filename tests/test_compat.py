@@ -36,16 +36,6 @@ def test_ambient_mesh_roundtrip():
     assert compat.get_abstract_mesh().empty
 
 
-def test_ambient_mesh_drives_constrain():
-    """distributed.constraints is a no-op outside a mesh, active inside."""
-    from repro.distributed.constraints import current_rules
-
-    assert current_rules() is None
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
-    with compat.set_mesh(mesh):
-        assert current_rules() is not None
-
-
 def test_shard_map_unified_signature():
     """``check`` is the one spelling of jax.shard_map's check_vma."""
     mesh = compat.make_mesh((1,), ("data",))

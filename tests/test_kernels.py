@@ -51,66 +51,6 @@ def test_bfs_dense_matches_edge_relax():
         assert np.all(same | ((dd >= k + 1) & (de >= k + 1)))
 
 
-# ---------------------------------------------------------------------------
-# flash attention
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("L,H,Hkv,D", [
-    (128, 4, 4, 64), (256, 8, 4, 64), (256, 8, 2, 32), (128, 8, 1, 128),
-])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_flash_attention_sweep(L, H, Hkv, D, dtype):
-    B = 2
-    q = jax.random.normal(jax.random.PRNGKey(0), (B, L, H, D), dtype)
-    k = jax.random.normal(jax.random.PRNGKey(1), (B, L, Hkv, D), dtype)
-    v = jax.random.normal(jax.random.PRNGKey(2), (B, L, Hkv, D), dtype)
-    got = ops.flash_attention(q, k, v, causal=True, bq=128, bk=128)
-    want = ref.mha_ref(q, k, v, causal=True)
-    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32), atol=tol)
-
-
-@pytest.mark.parametrize("window", [32, 64, 128])
-def test_flash_attention_window(window):
-    B, L, H, D = 1, 256, 4, 32
-    q = jax.random.normal(jax.random.PRNGKey(3), (B, L, H, D))
-    k = jax.random.normal(jax.random.PRNGKey(4), (B, L, H, D))
-    v = jax.random.normal(jax.random.PRNGKey(5), (B, L, H, D))
-    got = ops.flash_attention(q, k, v, causal=True, window=window,
-                              bq=128, bk=128)
-    want = ref.mha_ref(q, k, v, causal=True, window=window)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
-
-
-def test_flash_attention_ragged_fallback():
-    B, L, H, D = 1, 100, 4, 32   # non-tile-aligned -> padded/fallback paths
-    q = jax.random.normal(jax.random.PRNGKey(6), (B, L, H, D))
-    k = jax.random.normal(jax.random.PRNGKey(7), (B, L, H, D))
-    v = jax.random.normal(jax.random.PRNGKey(8), (B, L, H, D))
-    got = ops.flash_attention(q, k, v, causal=True)
-    want = ref.mha_ref(q, k, v, causal=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
-
-
-# ---------------------------------------------------------------------------
-# decode attention
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("S,H,Hkv,D", [
-    (512, 8, 2, 64), (1024, 8, 8, 32), (512, 16, 1, 64), (777, 4, 2, 32),
-])
-def test_decode_attention_sweep(S, H, Hkv, D):
-    B = 3
-    q = jax.random.normal(jax.random.PRNGKey(9), (B, H, D))
-    kc = jax.random.normal(jax.random.PRNGKey(10), (B, S, Hkv, D))
-    vc = jax.random.normal(jax.random.PRNGKey(11), (B, S, Hkv, D))
-    lens = jnp.array([S, max(1, S // 2), 3], jnp.int32)
-    got = ops.decode_attention(q, kc, vc, lens, bs=256)
-    want = ref.decode_attention_ref(q, kc, vc, lens)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
-
-
 def test_raw_kernels_require_alignment():
     with pytest.raises(AssertionError):
         raw_minplus(jnp.zeros((100, 100)), jnp.zeros((100,)), inf=INF,
